@@ -18,6 +18,7 @@ from benchmarks.common import emit_csv, require_dataset
 from repro.core.dataset import evaluate_bank, fit_predictor_bank
 from repro.core.fusion import fuse_graph
 from repro.core.realworld import build_realworld_suite
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(predictor: str = "gbdt", overhead_model: str = "affine") -> List[Dict]:
@@ -66,4 +67,5 @@ def run(predictor: str = "gbdt", overhead_model: str = "affine") -> List[Dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -19,6 +19,7 @@ from repro.core.fusion import fuse_graph
 from repro.core.nas_space import (RandomWiredConfig, decode_genotype,
                                   sample_random_wired)
 from repro.core.realworld import build_realworld_suite
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def diamond_collapse_row() -> Dict:
@@ -114,4 +115,5 @@ def run() -> List[Dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

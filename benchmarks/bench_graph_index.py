@@ -13,6 +13,7 @@ import time
 
 from repro.core.fusion import fuse_graph
 from repro.core.ir import OpGraph
+from repro.utils.compile_cache import enable_compile_cache
 from benchmarks.common import emit_csv
 
 N_OPS = 500
@@ -67,4 +68,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

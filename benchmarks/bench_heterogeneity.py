@@ -14,6 +14,7 @@ import numpy as np
 from benchmarks.common import emit_csv, load_dataset, require_dataset
 from repro.core.dataset import evaluate_bank, fit_predictor_bank
 from repro.distributed.straggler import StragglerMonitor
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(predictor: str = "gbdt", overhead_model: str = "affine") -> List[Dict]:
@@ -50,4 +51,5 @@ def run(predictor: str = "gbdt", overhead_model: str = "affine") -> List[Dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -18,6 +18,7 @@ from benchmarks.common import emit_csv
 from repro.core.executor import GraphExecutor
 from repro.core.ir import OpGraph
 from repro.core.selection import check_winograd, get_device
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.timing import time_callable
 
 
@@ -81,4 +82,5 @@ def run() -> List[Dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

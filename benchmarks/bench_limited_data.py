@@ -12,6 +12,7 @@ import numpy as np
 
 from benchmarks.common import emit_csv, require_dataset
 from repro.core.dataset import evaluate_bank, fit_predictor_bank
+from repro.utils.compile_cache import enable_compile_cache
 
 PREDICTORS = ("lasso", "rf", "gbdt", "mlp")
 
@@ -43,4 +44,5 @@ def run(setting: str = "cpu_f32", overhead_model: str = "affine") -> List[Dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

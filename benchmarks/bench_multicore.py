@@ -22,6 +22,7 @@ from benchmarks.common import emit_csv, require_dataset
 from repro.core.distributed_model import (
     Worker, graph_latency_multiworker, speedup_curve,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run() -> List[Dict]:
@@ -64,4 +65,5 @@ def run() -> List[Dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

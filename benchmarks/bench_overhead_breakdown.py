@@ -12,6 +12,7 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro.utils.compile_cache import enable_compile_cache
 from benchmarks.common import emit_csv, load_dataset, require_dataset
 
 
@@ -48,4 +49,5 @@ def run() -> List[Dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

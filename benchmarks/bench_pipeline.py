@@ -16,6 +16,7 @@ import time
 from repro.core.dataset import synthetic_graphs
 from repro.core.profiler import DeviceSetting, ProfileSession
 from repro.pipeline import LatencyService, ProfileStore
+from repro.utils.compile_cache import enable_compile_cache
 from benchmarks.common import REPORT_DIR, emit_csv
 
 N_ARCHS = 8
@@ -75,4 +76,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -5,10 +5,9 @@ Sections:
     per-row node-walk oracle vs flattened struct-of-arrays traversal
     (numpy) vs the jit'd jax gather backend;
   * backend crossover — numpy vs jax (resident bank) vs jax (cold bank,
-    re-uploaded per call — the pre-residency behaviour) vs pallas
-    across a rows×trees sweep, plus what "auto" resolves to at each
-    point.  Written to BENCH_predict.json at the repo root so the perf
-    trajectory is tracked across PRs;
+    re-uploaded per call — the pre-residency behaviour) across a
+    rows×trees sweep, plus what "auto" resolves to at each point.
+    Written to reports/bench_predict.json with the platform it ran on;
   * fused device scoring — host predict (float64 bounce) vs
     `predict_on_device` (standardize→traverse→reduce→clamp on device);
   * predict_batch — LatencyService multi-graph scoring, cold
@@ -27,12 +26,11 @@ import numpy as np
 
 from repro.core.features import clear_graph_feature_cache
 from repro.core.predictors import GBDTPredictor, RandomForestPredictor
-from repro.core.predictors.flat import (
-    AUTO_JAX_MIN_SLOTS, AUTO_PALLAS_MIN_SLOTS, resolve_backend,
-)
+from repro.core.predictors.flat import AUTO_JAX_MIN_SLOTS, resolve_backend
 from repro.core.dataset import synthetic_graphs
 from repro.core.profiler import DeviceSetting, ProfileSession
 from repro.pipeline import LatencyService
+from repro.utils.compile_cache import enable_compile_cache
 from benchmarks.common import emit_bench_json, emit_csv
 
 N_ROWS = 512
@@ -51,13 +49,8 @@ def _bench(fn, *args, repeats=5):
 
 
 def _crossover(rows_list, smoke):
-    """numpy / jax-resident / jax-cold / pallas sweep over flush sizes."""
-    try:
-        import jax
-        backend_platform = jax.default_backend()
-    except Exception:
-        return None
-    from repro.kernels.tree_gather_pallas import HAS_PALLAS
+    """numpy / jax-resident / jax-cold sweep over flush sizes."""
+    import jax
 
     n_trees = 50 if smoke else N_TREES
     rng = np.random.default_rng(7)
@@ -69,11 +62,6 @@ def _crossover(rows_list, smoke):
     q = np.abs(rng.standard_normal((max(rows_list), N_FEATURES))) \
         * np.linspace(1, 40, N_FEATURES)
     xs = m.scaler.transform(q)
-    # Interpret-mode pallas (CPU CI) is a correctness path: orders of
-    # magnitude slower than compiled, so point it at a capped flush and
-    # record the mode so the curve is read in context.
-    pallas_mode = "compiled" if backend_platform == "tpu" else "interpret"
-    pallas_row_cap = None if pallas_mode == "compiled" else 2048
 
     curve = []
     for rows in rows_list:
@@ -91,9 +79,6 @@ def _crossover(rows_list, smoke):
         point["jax_cold_bank_ms"] = 1e3 * _bench(jax_cold)
         flat._device_bank = None              # leave a fresh bank behind
         flat.predict_trees(xq, "jax")
-        if HAS_PALLAS and (pallas_row_cap is None or rows <= pallas_row_cap):
-            point["pallas_ms"] = 1e3 * _bench(flat.predict_trees, xq,
-                                              "pallas")
         point["auto_ms"] = 1e3 * _bench(flat.predict_trees, xq, "auto")
         curve.append(point)
 
@@ -125,10 +110,10 @@ def _crossover(rows_list, smoke):
 
     db = flat._device_bank
     return {
-        "platform": backend_platform,
-        "pallas_mode": pallas_mode,
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
         "auto_jax_min_slots": AUTO_JAX_MIN_SLOTS,
-        "auto_pallas_min_slots": AUTO_PALLAS_MIN_SLOTS,
         "crossover": curve,
         "fused": fused,
         "residency": db.stats() if db is not None else None,
@@ -156,37 +141,32 @@ def run(smoke: bool = False) -> None:
                      "derived": f"{N_ROWS} rows x {N_TREES} trees, per-row node walk"})
         rows.append({"name": f"{name}_flat_ms", "value": f"{1e3 * t_flat:.2f}",
                      "derived": f"{t_oracle / t_flat:.1f}x faster, bit-identical"})
+        m.inference_backend = "jax"
         try:
-            m.inference_backend = "jax"
             t_jax = _bench(m.predict, q)
-            rows.append({"name": f"{name}_jax_ms", "value": f"{1e3 * t_jax:.2f}",
-                         "derived": f"{t_oracle / t_jax:.1f}x vs oracle (jit gathers)"})
-        except Exception as e:                     # jax unavailable
-            rows.append({"name": f"{name}_jax_ms", "value": "n/a",
-                         "derived": f"skipped: {e}"})
         finally:
             m.inference_backend = "numpy"
+        rows.append({"name": f"{name}_jax_ms", "value": f"{1e3 * t_jax:.2f}",
+                     "derived": f"{t_oracle / t_jax:.1f}x vs oracle (jit gathers)"})
 
-    # -- backend crossover curve (numpy / jax / pallas) ----------------------
+    # -- backend crossover curve (numpy / jax) -------------------------------
     rows_list = [64, 512, 2048] if smoke else [64, 256, 1024, 4096, 16384]
     xover = _crossover(rows_list, smoke)
-    if xover is not None:
-        for p in xover["crossover"]:
-            derived = [f"auto→{p['auto_resolves_to']}"]
-            for k in ("numpy_ms", "jax_resident_ms", "jax_cold_bank_ms",
-                      "pallas_ms", "auto_ms"):
-                if k in p:
-                    derived.append(f"{k.removesuffix('_ms')}={p[k]:.2f}ms")
-            rows.append({"name": f"crossover_{p['rows']}x{p['trees']}",
-                         "value": str(p["slots"]),
-                         "derived": " ".join(derived)})
-        f = xover["fused"]
-        rows.append({"name": "fused_device_ms",
-                     "value": f"{f['device_fused_ms']:.2f}",
-                     "derived": f"{f['speedup']:.1f}x vs host float64 "
-                                f"({f['host_float64_ms']:.2f}ms) at "
-                                f"{f['rows']} rows"})
-        emit_bench_json("bench_predict", xover)
+    for p in xover["crossover"]:
+        derived = [f"auto→{p['auto_resolves_to']}"]
+        for k in ("numpy_ms", "jax_resident_ms", "jax_cold_bank_ms",
+                  "auto_ms"):
+            derived.append(f"{k.removesuffix('_ms')}={p[k]:.2f}ms")
+        rows.append({"name": f"crossover_{p['rows']}x{p['trees']}",
+                     "value": str(p["slots"]),
+                     "derived": " ".join(derived)})
+    f = xover["fused"]
+    rows.append({"name": "fused_device_ms",
+                 "value": f"{f['device_fused_ms']:.2f}",
+                 "derived": f"{f['speedup']:.1f}x vs host float64 "
+                            f"({f['host_float64_ms']:.2f}ms) at "
+                            f"{f['rows']} rows"})
+    emit_bench_json("bench_predict", xover)
 
     # -- predict_batch featurization: cold vs warm GraphFeatures cache ------
     setting = DeviceSetting("cpu_f32", "float32", "op_by_op")
@@ -221,4 +201,6 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small sweep for CI")
-    run(smoke=ap.parse_args().smoke)
+    args = ap.parse_args()
+    enable_compile_cache()
+    run(smoke=args.smoke)

@@ -12,6 +12,7 @@ import numpy as np
 
 from benchmarks.common import emit_csv, load_dataset, require_dataset
 from repro.core.dataset import evaluate_bank, fit_predictor_bank
+from repro.utils.compile_cache import enable_compile_cache
 
 PREDICTORS = ("lasso", "rf", "gbdt", "mlp")
 KEY_OPS = ("conv2d", "dwconv2d", "mean", "pool_avg", "pool_max",
@@ -49,4 +50,5 @@ def run(settings=("cpu_f32", "cpu_int8", "gpu_f32"),
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

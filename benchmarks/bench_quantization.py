@@ -13,6 +13,7 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro.utils.compile_cache import enable_compile_cache
 from benchmarks.common import emit_csv, require_dataset
 
 
@@ -45,4 +46,5 @@ def run() -> List[Dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

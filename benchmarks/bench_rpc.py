@@ -46,6 +46,7 @@ from repro.rpc.batcher import BatchPolicy, MicroBatcher, MonotonicClock
 from repro.rpc.chaos import FaultPlan, FaultSpec
 from repro.rpc.protocol import E_UNAVAILABLE, RPCError
 from repro.transfer import CostModelProfileSession
+from repro.utils.compile_cache import enable_compile_cache
 from benchmarks.common import emit_bench_json, emit_csv
 
 SETTING = DeviceSetting("cpu_f32", "float32", "op_by_op")
@@ -401,7 +402,6 @@ def run(smoke: bool = False) -> None:
         "avg_batch": round(st_auto["avg_batch"], 2),
         "backend_numpy_runs": runs.get("numpy", 0),
         "backend_jax_runs": runs.get("jax", 0),
-        "backend_pallas_runs": runs.get("pallas", 0),
         "flush_backends": str(flush_backends),
         "device_fused_runs": auto_stats["device_fused_runs"],
         "max_abs_delta_vs_numpy_s": float(np.max(deltas)),
@@ -437,4 +437,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

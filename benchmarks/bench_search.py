@@ -41,6 +41,7 @@ from repro.pipeline import LatencyService, PredictorHub, ProfileStore
 from repro.search import DeviceBudget, SearchConfig, SearchEngine
 from repro.search.encoding import decode
 from repro.transfer import CostModelProfileSession
+from repro.utils.compile_cache import enable_compile_cache
 from benchmarks.common import emit_csv
 
 SETTING = DeviceSetting("cpu_f32", "float32", "op_by_op")
@@ -214,4 +215,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

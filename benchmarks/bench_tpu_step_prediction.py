@@ -20,6 +20,7 @@ from repro.configs import ARCHS, INPUT_SHAPES
 from repro.core.cost_model import op_cost
 from repro.core.ir import OpGraph
 from repro.core.predictors import make_predictor
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _lm_op_dataset(n: int = 400, seed: int = 0):
@@ -79,4 +80,5 @@ def run() -> List[Dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -28,6 +28,7 @@ from repro.core.profiler import DeviceSetting, ProfileSession
 from repro.pipeline import LatencyService, PredictorHub, ProfileStore
 from repro.transfer import (CostModelProfileSession, ReplayProfileSession,
                             SyntheticDevice, TransferEngine)
+from repro.utils.compile_cache import enable_compile_cache
 from benchmarks.common import REPORT_DIR, emit_csv
 
 SOURCE = DeviceSetting("cpu_f32", "float32", "op_by_op")
@@ -115,4 +116,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -19,6 +19,7 @@ import time
 
 from repro.core.dataset import build_dataset, realworld_graphs, synthetic_graphs
 from repro.core.profiler import DeviceSetting, ProfileSession
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("repro.bench.data")
@@ -71,4 +72,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,9 +12,8 @@ from repro.core.dataset import LatencyDataset
 from benchmarks.build_datasets import DATA_DIR, dataset_path
 
 REPORT_DIR = os.path.join(os.path.dirname(__file__), "..", "reports")
-# Machine-readable perf trajectory, tracked at the repo root across PRs.
-BENCH_JSON = os.path.join(os.path.dirname(__file__), "..",
-                          "BENCH_predict.json")
+# Machine-readable bench output; each section names the platform it ran on.
+BENCH_JSON = os.path.join(REPORT_DIR, "bench_predict.json")
 
 
 def load_dataset(kind: str, setting: str) -> Optional[LatencyDataset]:
@@ -54,11 +53,10 @@ def emit_csv(name: str, rows: Sequence[Dict[str, Any]],
 
 
 def emit_bench_json(section: str, payload: Dict[str, Any]) -> None:
-    """Merge ``payload`` under ``section`` into BENCH_predict.json.
+    """Merge ``payload`` under ``section`` into reports/bench_predict.json.
 
     Read-modify-write so bench_predict and bench_rpc each own a section
-    without clobbering the other; the file at the repo root is the
-    cross-PR perf trajectory (crossover curves, resolved-tier counts).
+    without clobbering the other.
     """
     data: Dict[str, Any] = {}
     if os.path.exists(BENCH_JSON):
@@ -68,6 +66,7 @@ def emit_bench_json(section: str, payload: Dict[str, Any]) -> None:
         except Exception:
             data = {}
     data[section] = payload
+    os.makedirs(REPORT_DIR, exist_ok=True)
     with open(BENCH_JSON, "w") as f:
         json.dump(data, f, indent=2, sort_keys=True)
         f.write("\n")

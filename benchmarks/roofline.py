@@ -30,6 +30,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from repro.configs import ARCHS, INPUT_SHAPES, get_arch
+from repro.utils.compile_cache import enable_compile_cache
 
 PEAK_FLOPS = 197e12        # bf16 / chip
 HBM_BW = 819e9             # bytes/s / chip
@@ -196,6 +197,7 @@ def run(report: str = REPORT, single_pod_only: bool = True) -> List[Dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--report", default=REPORT)
     ap.add_argument("--all-meshes", action="store_true")

@@ -13,6 +13,8 @@ import argparse
 import time
 import traceback
 
+from repro.utils.compile_cache import enable_compile_cache
+
 BENCHES = [
     # Pipeline rows are self-contained (no prebuilt datasets): the full
     # ProfileStore → PredictorHub → LatencyService.predict_e2e path and
@@ -63,4 +65,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -31,6 +31,7 @@ from repro.pipeline import LatencyService, PredictorHub, ProfileStore
 from repro.rpc.batcher import ManualClock
 from repro.transfer import (CostModelProfileSession, ReplayProfileSession,
                             SyntheticDevice, TransferEngine)
+from repro.utils.compile_cache import enable_compile_cache
 
 SOURCE = DeviceSetting("cpu_f32", "float32", "op_by_op")
 TARGET = DeviceSetting("edge_f32", "float32", "op_by_op", device="edge_sim")
@@ -104,4 +105,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -27,6 +27,7 @@ from repro.pipeline import LatencyService
 from repro.search import DeviceBudget, SearchConfig, SearchEngine
 from repro.transfer import (ReplayProfileSession, SyntheticDevice,
                             TransferEngine)
+from repro.utils.compile_cache import enable_compile_cache
 
 STORE = os.path.join(os.path.dirname(__file__), "..", "reports",
                      "nas_search_store.jsonl")
@@ -101,4 +102,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from benchmarks.roofline import HBM_BW, LINK_BW, PEAK_FLOPS, analytic_costs
 from repro.configs import ARCHS, INPUT_SHAPES, shape_applicable, get_arch
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -43,4 +44,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

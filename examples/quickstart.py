@@ -18,6 +18,7 @@ from repro.core.fusion import fuse_graph
 from repro.core.profiler import DeviceSetting, ProfileSession
 from repro.core.selection import apply_selection, get_device
 from repro.pipeline import LatencyService
+from repro.utils.compile_cache import enable_compile_cache
 
 STORE = os.path.join(os.path.dirname(__file__), "..", "reports",
                      "quickstart_store.jsonl")
@@ -62,4 +63,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -30,6 +30,7 @@ from repro.core.profiler import DeviceSetting
 from repro.pipeline import LatencyService, PredictorHub, ProfileStore
 from repro.search import DeviceBudget, SearchConfig, SearchEngine
 from repro.transfer import CostModelProfileSession
+from repro.utils.compile_cache import enable_compile_cache
 
 SETTING = DeviceSetting("cpu_f32", "float32", "op_by_op")
 
@@ -112,4 +113,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -32,6 +32,7 @@ from repro.pipeline import LatencyService, PredictorHub, ProfileStore
 from repro.rpc import BatchPolicy, LatencyClient, LatencyRPCServer
 from repro.search import DeviceBudget, SearchConfig, SearchEngine
 from repro.transfer import CostModelProfileSession
+from repro.utils.compile_cache import enable_compile_cache
 
 SETTING = DeviceSetting("cpu_f32", "float32", "op_by_op")
 SPACE = NASSpaceConfig(resolution=16)
@@ -133,4 +134,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,7 @@ import numpy as np
 from repro.configs import get_arch
 from repro.models import build_model
 from repro.serving import ServeEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -37,4 +38,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -26,6 +26,7 @@ from repro.core.dataset import synthetic_graphs
 from repro.core.profiler import DeviceSetting, ProfileSession
 from repro.pipeline import LatencyService, PredictorHub, ProfileStore
 from repro.transfer import ReplayProfileSession, SyntheticDevice, TransferEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 STORE = os.path.join(os.path.dirname(__file__), "..", "reports",
                      "transfer_source_store.jsonl")
@@ -93,4 +94,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -30,7 +30,6 @@ enabled) and is opt-in for large batches.
 """
 from __future__ import annotations
 
-import os
 import threading
 from typing import Any, Dict, Optional, Sequence, Tuple
 
@@ -38,11 +37,6 @@ import numpy as np
 
 # rows × trees above which backend="auto" prefers the jax gather kernel.
 AUTO_JAX_MIN_SLOTS = 1 << 16
-# rows × trees above which backend="auto" prefers the Pallas kernel when
-# a real accelerator backs it (measured crossover: below this the
-# pallas_call dispatch overhead eats the tiling win; see
-# docs/PIPELINE.md for the curve and BENCH_predict.json for raw data).
-AUTO_PALLAS_MIN_SLOTS = 1 << 20
 
 
 def resolve_backend(backend: str, n_slots: int) -> str:
@@ -53,16 +47,12 @@ def resolve_backend(backend: str, n_slots: int) -> str:
     will take (`LatencyService.stats`) resolve through it, so the
     thresholds cannot drift between decision and bookkeeping.
 
-    Three tiers: numpy (small, bit-exact) → jax gather (≥ 2^16 slots)
-    → pallas kernel (≥ 2^20 slots AND a compiled — non-interpret —
-    Pallas backend; on CPU-only hosts "auto" tops out at jax because
-    interpret mode is a correctness path, not a fast path).
+    Two tiers: numpy (small, bit-exact) below 2^16 slots, the jax
+    gather on JAX's default device at or above it.  A device error
+    propagates; it never falls back to numpy.
     """
     if backend == "auto":
-        if n_slots >= AUTO_PALLAS_MIN_SLOTS and _pallas_available():
-            return "pallas"
-        return ("jax" if n_slots >= AUTO_JAX_MIN_SLOTS and _jax_available()
-                else "numpy")
+        return "jax" if n_slots >= AUTO_JAX_MIN_SLOTS else "numpy"
     return backend
 
 
@@ -163,8 +153,7 @@ class FlatEnsemble:
         """Leaf value of every tree for every row → (n_rows, n_trees).
 
         ``backend``: "numpy" (default, bit-exact float64), "jax" (jit'd
-        gather loop on the resident bank), "pallas" (tiled Pallas
-        kernel; interpret mode off-TPU), or "auto" (tiered by
+        gather loop on the resident bank), or "auto" (tiered by
         `resolve_backend`).
         """
         x = np.ascontiguousarray(x, dtype=np.float64)
@@ -175,9 +164,6 @@ class FlatEnsemble:
         if backend == "jax":
             from repro.kernels.tree_gather import predict_trees_jax
             return predict_trees_jax(self, x)
-        if backend == "pallas":
-            from repro.kernels.tree_gather_pallas import predict_trees_pallas
-            return predict_trees_pallas(self, x)
         if backend != "numpy":
             raise ValueError(f"unknown tree backend {backend!r}")
         return self._predict_trees_np(x)
@@ -195,34 +181,6 @@ class FlatEnsemble:
         return self.value[nid].reshape(n, t)
 
 
-def _jax_available() -> bool:
-    try:
-        from repro.kernels.tree_gather import HAS_JAX
-        return HAS_JAX
-    except Exception:                                 # pragma: no cover
-        return False
-
-
-def _pallas_available() -> bool:
-    """True when "auto" may tier up to the Pallas kernel.
-
-    Requires a compiled Pallas backend (TPU today): interpret mode runs
-    the kernel body in Python, which is orders of magnitude slower than
-    the jax gather — it exists for CPU CI parity, never for serving.
-    Set ``REPRO_AUTO_PALLAS=1`` to override (bench/curve exploration).
-    """
-    try:
-        from repro.kernels.tree_gather_pallas import HAS_PALLAS
-        if not HAS_PALLAS:
-            return False
-        if os.environ.get("REPRO_AUTO_PALLAS") == "1":
-            return True
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:                                 # pragma: no cover
-        return False
-
-
 class FlattenedTreeModel:
     """Lazy-flattening state shared by the tree-ensemble predictors.
 
@@ -236,20 +194,19 @@ class FlattenedTreeModel:
 
     def _init_flat(self) -> None:
         self._flat: Optional[FlatEnsemble] = None
-        # Runtime knob (not serialized model state): numpy | jax | pallas
-        # | auto.
+        # Runtime knob (not serialized model state): numpy | jax | auto.
         self.inference_backend = "numpy"
         # Serializes swap-predict-restore of the knob by batch servers
         # (`LatencyService._run_model`): per model, so two threads
         # serving *different* banks still predict in parallel.
         self.backend_swap_lock = threading.Lock()
-        # Resident (mean, std) device pair for the fused path; rebuilt
+        # Resident raw-space thresholds for the fused path; rebuilt
         # lazily after any invalidation (refit changes the scaler too).
-        self._device_scaler: Optional[Tuple] = None
+        self._device_thresholds: Optional[Any] = None
 
     def _invalidate_flat(self) -> None:
         self._flat = None          # drops the DeviceBank riding on it
-        self._device_scaler = None
+        self._device_thresholds = None
 
     def flat(self) -> FlatEnsemble:
         """All trees compiled into one contiguous node bank (lazy)."""
@@ -272,13 +229,13 @@ class FlattenedTreeModel:
         """
         return None
 
-    def predict_on_device(self, x: np.ndarray, backend: str = "jax"
-                          ) -> np.ndarray:
+    def predict_on_device(self, x: np.ndarray) -> np.ndarray:
         """Raw (unstandardized) float32 features → clamped predictions,
-        with standardize/traverse/reduce all on-device (no float64
-        (rows × trees) bounce through the host).  Float32 end-to-end;
+        with traverse/reduce on-device against thresholds folded into
+        raw feature units (no float64 (rows × trees) bounce through the
+        host).  Float32 end-to-end;
         `LatencyService` only routes here when `resolve_backend` already
-        picked a device tier.
+        picked the device tier.
         """
         red = self._device_reduction()
         if red is None:
@@ -286,10 +243,10 @@ class FlattenedTreeModel:
                 f"{type(self).__name__} has no device reduction")
         from repro.kernels import tree_gather as tg
 
-        if self._device_scaler is None:
-            self._device_scaler = tg.to_device_scaler(self.scaler)
-        return tg.fused_predict(self.flat(), self._device_scaler, red, x,
-                                backend=backend)
+        if self._device_thresholds is None:
+            self._device_thresholds = tg.raw_thresholds(self.flat(),
+                                                        self.scaler)
+        return tg.fused_predict(self.flat(), self._device_thresholds, red, x)
 
     def device_stats(self) -> Optional[Dict[str, Any]]:
         """Residency snapshot of this model's bank, or None if nothing

@@ -1,4 +1,8 @@
-"""Wall-clock profiling of op graphs on the CPU device (paper §4.3.1).
+"""Wall-clock profiling of op graphs on JAX's default device (paper §4.3.1).
+
+The session measures whatever device JAX runs on — a TPU, a GPU or the
+CPU — so a `DeviceSetting` names that device (``device`` tag, or a name
+from ``jax.devices()[0].device_kind``) rather than assuming one.
 
 A `ProfileSession` measures
   * per-op latency (cached by op signature — the paper profiles unique
@@ -19,11 +23,10 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.executor import GraphExecutor, make_array
+from repro.core.executor import GraphExecutor, JitOp, make_array
 from repro.core.features import featurize, graph_features
 from repro.core.ir import OpGraph, OpNode, op_signature
 from repro.utils.logging import get_logger
@@ -252,7 +255,7 @@ class ProfileSession:
         jfn = self.fn_cache.get(sig)
         if jfn is None:
             fn, _ = builder(graph, node)
-            jfn = jax.jit(fn)
+            jfn = JitOp(fn)
             self.fn_cache[sig] = jfn
         args = self._op_inputs(graph, node, setting.dtype)
         # Adaptive amortization (paper §4.3.1 dispatches the same kernel

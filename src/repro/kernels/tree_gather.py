@@ -7,22 +7,20 @@ self` in `FlatEnsemble`), the update is idempotent, so a fixed-depth
 `lax.fori_loop` of ``max_depth`` iterations needs no active mask — rows
 that reached a leaf simply stay put.  That keeps the whole traversal one
 XLA computation (no host sync per level), which wins once
-rows × trees is large; the numpy mask loop wins on small batches, and
-the Pallas kernel (`repro.kernels.tree_gather_pallas`) wins above that.
+rows × trees is large; the numpy mask loop wins on small batches.
 
 Residency (`DeviceBank`): the flattened struct-of-arrays bank is
 uploaded to the accelerator ONCE per `FlatEnsemble` and reused across
 every subsequent flush — the bank arrays live on `flat._device_bank`
 until the ensemble itself is invalidated (retrain / bank swap), so a
 serving process pays host→device transfer of the trees exactly once.
-Inputs are staged through the same layer: float32 (half the bytes of
-the old float64 bounce) and donated to the jit'd traversal, so
-repeat-shape flushes let XLA recycle the input buffer instead of
-accumulating live copies.
+Inputs are staged through the same layer as float32 (half the bytes of
+a float64 bounce).  They are not donated: no output of the traversal
+has an input's shape, so XLA could not reuse the buffer.
 
 Sharding: when the process sees more than one accelerator, the bank is
 built against a 1-axis ``("rows",)`` mesh (`repro.launch.mesh.flush_mesh`)
-— bank arrays replicated, flush rows sharded via `shard_map`, results
+— bank arrays replicated, flush rows sharded via `jax.shard_map`, results
 reassembled deterministically in row order (rows are padded to a device
 multiple and the pad sliced off, so reassembly is a plain row-major
 gather).
@@ -30,24 +28,21 @@ gather).
 Precision: runs at jax's default precision (float32 unless x64 is
 enabled), so predictions can differ from the float64 numpy backend in
 the last ulps — and near-tie thresholds can route differently.  The
-numpy backend stays the bit-exact default; device tiers are opt-in
-(``backend="jax"|"pallas"`` / ``"auto"``) for large-batch NAS scoring.
+numpy backend stays the bit-exact default; the device tier is opt-in
+(``backend="jax"`` / ``"auto"``) for large-batch NAS scoring.
 """
 from __future__ import annotations
 
 import threading
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    HAS_JAX = True
-except Exception:                                     # pragma: no cover
-    HAS_JAX = False
+from repro.launch.mesh import flush_mesh
 
 # Lifetime counters (survive bank invalidation — `DeviceBank` instances
 # die with their FlatEnsemble, these do not).  `LatencyService.stats()`
@@ -73,41 +68,37 @@ def _count(**deltas: int) -> None:
             _COUNTERS[k] += v
 
 
-if HAS_JAX:
-    def _traverse_core(feature, threshold, left, right, value, roots, x,
-                       *, depth):
-        n = x.shape[0]
-        nid = jnp.tile(roots[None, :], (n, 1))            # (rows, trees)
+def _traverse_core(feature, threshold, left, right, value, roots, x, *,
+                   depth):
+    # Level 0 is peeled out of the loop: every row starts at the same
+    # roots, so it is a plain column gather, and the loop carry it
+    # yields is built from ``x`` — under `shard_map` that makes the
+    # carry vary over the rows axis like the loop's output does.
+    first = jnp.where(x[:, feature[roots]] <= threshold[roots],
+                      left[roots], right[roots])         # (rows, trees)
 
-        def body(_, nid):
-            f = feature[nid]                              # gather per slot
-            thr = threshold[nid]
-            xv = jnp.take_along_axis(x, f, axis=1)        # x[row, f[row, tree]]
-            return jnp.where(xv <= thr, left[nid], right[nid])
+    def body(_, nid):
+        f = feature[nid]                                  # gather per slot
+        thr = threshold[nid]
+        xv = jnp.take_along_axis(x, f, axis=1)            # x[row, f[row, tree]]
+        return jnp.where(xv <= thr, left[nid], right[nid])
 
-        nid = lax.fori_loop(0, depth, body, nid)
-        return value[nid]
+    nid = lax.fori_loop(1, depth, body, first)
+    return value[nid]
 
-    # Input donation: the staged f32 buffer is consumed by the call, so
-    # XLA reuses its memory on the next same-shape flush instead of
-    # holding both copies live (the residency layer's input half).
-    # The CPU backend cannot honor donation and warns per call, so only
-    # ask for it where it works.
-    _DONATE = ({"donate_argnames": ("x",)}
-               if jax.default_backend() in ("tpu", "gpu") else {})
-    _traverse = jax.jit(_traverse_core, static_argnames=("depth",),
-                        **_DONATE)
 
-    def _fused_core(feature, threshold, left, right, value, roots,
-                    mean, std, scale, bias, x, *, depth, kind):
-        xs = (x - mean) / std                             # standardize on device
-        vals = _traverse_core(feature, threshold, left, right, value,
-                              roots, xs, depth=depth)
-        red = jnp.sum(vals, axis=1) if kind == "sum" else jnp.mean(vals, axis=1)
-        return jnp.maximum(bias + scale * red, 0.0)       # Predictor.predict clamp
+def _fused_core(feature, raw_threshold, left, right, value, roots,
+                scale, bias, x, *, depth, kind):
+    # Raw features against raw-space thresholds (`raw_thresholds`): the
+    # standardization is folded into the bank, not computed in float32.
+    vals = _traverse_core(feature, raw_threshold, left, right, value,
+                          roots, x, depth=depth)
+    red = jnp.sum(vals, axis=1) if kind == "sum" else jnp.mean(vals, axis=1)
+    return jnp.maximum(bias + scale * red, 0.0)           # Predictor.predict clamp
 
-    _fused = jax.jit(_fused_core, static_argnames=("depth", "kind"),
-                     **_DONATE)
+
+_traverse = jax.jit(_traverse_core, static_argnames=("depth",))
+_fused = jax.jit(_fused_core, static_argnames=("depth", "kind"))
 
 
 class DeviceBank:
@@ -124,10 +115,9 @@ class DeviceBank:
     __slots__ = ("n_nodes", "n_trees", "depth", "feature", "threshold",
                  "left", "right", "value", "roots", "mesh", "nbytes",
                  "uploads", "inputs_staged", "input_bytes",
-                 "_pallas_args", "_fn_cache", "_lock")
+                 "_fn_cache", "_lock")
 
     def __init__(self) -> None:
-        self._pallas_args: Optional[Tuple] = None
         self._fn_cache: Dict[Tuple, Any] = {}
         self._lock = threading.Lock()
         self.mesh = None
@@ -137,33 +127,32 @@ class DeviceBank:
 
     @classmethod
     def from_flat(cls, flat) -> "DeviceBank":
-        if not HAS_JAX:                                   # pragma: no cover
-            raise RuntimeError("jax is unavailable — use the numpy tree backend")
         db = cls()
         db.n_nodes = flat.n_nodes
         db.n_trees = flat.n_trees
         db.depth = max(1, flat.max_depth)
-        db.mesh = _flush_mesh()
+        db.mesh = flush_mesh()
         # Leaves carry feature = -1; clamp to 0 so the take_along_axis
         # gather stays in-bounds (self-looped slots ignore the compare).
         host = (np.maximum(flat.feature, 0).astype(np.int32),
-                flat.threshold.astype(np.float32),
+                f32_thresholds(flat.threshold, 0.0, 1.0),
                 flat.left.astype(np.int32),
                 flat.right.astype(np.int32),
                 flat.value.astype(np.float32),
                 flat.roots.astype(np.int32))
-        if db.mesh is not None:
-            repl = jax.sharding.NamedSharding(db.mesh,
-                                              jax.sharding.PartitionSpec())
-            dev = tuple(jax.device_put(a, repl) for a in host)
-        else:
-            dev = tuple(jnp.asarray(a) for a in host)
         (db.feature, db.threshold, db.left, db.right, db.value,
-         db.roots) = dev
+         db.roots) = (db.place(a) for a in host)
         db.nbytes = sum(a.nbytes for a in host)
         db.uploads = 1
         _count(banks_built=1, bank_bytes=db.nbytes)
         return db
+
+    def place(self, a: np.ndarray):
+        """Host array → device, replicated over the mesh when there is one."""
+        if self.mesh is None:
+            return jnp.asarray(a)
+        return jax.device_put(a, jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec()))
 
     @property
     def bank_args(self) -> Tuple:
@@ -171,7 +160,7 @@ class DeviceBank:
                 self.value, self.roots)
 
     # -- input staging --------------------------------------------------------
-    def stage_input(self, x: np.ndarray, *, sharded: bool = True):
+    def stage_input(self, x: np.ndarray):
         """Host rows → committed f32 device array (row-sharded on a mesh).
 
         Rows are padded up to a device multiple when sharding; callers
@@ -179,7 +168,7 @@ class DeviceBank:
         gather is what makes multi-device reassembly deterministic.
         """
         x32 = np.ascontiguousarray(x, dtype=np.float32)
-        mesh = self.mesh if (sharded and len(x32) >= SHARD_MIN_ROWS) else None
+        mesh = self.mesh if len(x32) >= SHARD_MIN_ROWS else None
         if mesh is not None:
             ndev = mesh.devices.size
             pad = (-len(x32)) % ndev
@@ -202,11 +191,9 @@ class DeviceBank:
         """`shard_map`-wrapped jit of ``core`` over the rows axis (cached)."""
         fn = self._fn_cache.get(key)
         if fn is None:
-            from jax.experimental.shard_map import shard_map
-
             P = jax.sharding.PartitionSpec
-            n_repl = 6 if out_rank2 else 10
-            fn = jax.jit(shard_map(
+            n_repl = 6 if out_rank2 else 8
+            fn = jax.jit(jax.shard_map(
                 core, mesh=self.mesh,
                 in_specs=(P(),) * n_repl + (P("rows", None),),
                 out_specs=P("rows", None) if out_rank2 else P("rows")))
@@ -224,16 +211,17 @@ class DeviceBank:
             return fn(*self.bank_args, xd)
         return _traverse(*self.bank_args, xd, depth=self.depth)
 
-    def fused(self, mean, std, scale, bias, xd, kind: str) -> Any:
-        """standardize → traverse → reduce → clamp, one device program."""
+    def fused(self, raw_threshold, scale, bias, xd, kind: str) -> Any:
+        """traverse raw rows → reduce → clamp, one device program."""
+        args = (self.feature, raw_threshold, self.left, self.right,
+                self.value, self.roots, scale, bias, xd)
         if self.mesh is not None and _row_sharded(xd):
             fn = self._sharded_fn(("fused", self.depth, kind),
                                   partial(_fused_core, depth=self.depth,
                                           kind=kind),
                                   out_rank2=False)
-            return fn(*self.bank_args, mean, std, scale, bias, xd)
-        return _fused(*self.bank_args, mean, std, scale, bias, xd,
-                      depth=self.depth, kind=kind)
+            return fn(*args)
+        return _fused(*args, depth=self.depth, kind=kind)
 
     # -- introspection --------------------------------------------------------
     def stats(self) -> Dict[str, Any]:
@@ -251,64 +239,80 @@ def _row_sharded(xd) -> bool:
     return bool(spec) and spec[0] == "rows"
 
 
-def _flush_mesh():
-    """1-axis ``("rows",)`` mesh over local devices, or None (1 device)."""
-    try:
-        from repro.launch.mesh import flush_mesh
-        return flush_mesh()
-    except Exception:                                 # pragma: no cover
-        return None
-
-
 # -- public backends ----------------------------------------------------------
 
 def predict_trees_jax(flat, x: np.ndarray) -> np.ndarray:
     """(n_rows, n_trees) leaf values via the jit'd gather loop.
 
     Bank arrays come from the persistent `DeviceBank` (uploaded once per
-    ensemble); the input is staged f32 + donated, so repeat-shape
-    flushes recycle buffers instead of re-transferring the bank.
+    ensemble); only the f32 input is transferred per flush.
     """
-    if not HAS_JAX:                                       # pragma: no cover
-        raise RuntimeError("jax is unavailable — use the numpy tree backend")
     db = flat.device_bank()
     n = x.shape[0]
     out = db.gather_leaves(db.stage_input(x))
     return np.asarray(out[:n], dtype=np.float64)
 
 
-def to_device_scaler(scaler) -> Tuple:
-    """(mean, std) as resident f32 device arrays (cached by the model)."""
-    return (jnp.asarray(scaler.mean.astype(np.float32)),
-            jnp.asarray(scaler.std.astype(np.float32)))
+def f32_thresholds(threshold: np.ndarray, mean, std) -> np.ndarray:
+    """Largest float32 ``v`` per node that the float64 host routes left.
+
+    The host sends a row left when ``(x - mean) / std <= threshold`` in
+    float64; that test is monotone in ``x``, so one float32 cut-off per
+    node reproduces it exactly for every float32-representable ``x``
+    (integer features below 2²⁴, which is most of them) — including a
+    row that sits exactly on a split midpoint, where the host's answer
+    depends on float64 rounding.  ``mean=0, std=1`` gives the cut-off
+    for already-standardized rows.
+    """
+    def left(v):
+        return (v.astype(np.float64) - mean) / std <= threshold
+
+    inf = np.float32(np.inf)
+    t = (threshold * std + mean).astype(np.float32)
+    # Rounding to float32 lands within a step or two of the cut-off.
+    for _ in range(8):
+        up = np.nextafter(t, inf)
+        go_up, go_down = left(up), ~left(t)
+        if not (go_up.any() or go_down.any()):
+            return t
+        t = np.where(go_up, up, np.where(go_down, np.nextafter(t, -inf), t))
+    raise RuntimeError("float32 thresholds did not converge")
 
 
-def fused_predict(flat, device_scaler: Tuple, reduction: Tuple,
-                  x: np.ndarray, backend: str = "jax") -> np.ndarray:
+def raw_thresholds(flat, scaler):
+    """Device thresholds in raw feature units, for the fused path.
+
+    The fused program compares raw float32 features with these
+    (`f32_thresholds` of the model's scaler), so the standardization is
+    folded in on the host.  Standardizing on the device instead cancels
+    catastrophically in float32 for large, clustered features (FLOPs,
+    bytes) and routes rows far from any threshold wrongly.
+    """
+    db = flat.device_bank()
+    f = np.maximum(flat.feature, 0)
+    raw = db.place(f32_thresholds(flat.threshold, scaler.mean[f],
+                                  scaler.std[f]))
+    with db._lock:
+        db.nbytes += raw.nbytes
+    _count(bank_bytes=raw.nbytes)
+    return raw
+
+
+def fused_predict(flat, raw_threshold, reduction: Tuple,
+                  x: np.ndarray) -> np.ndarray:
     """Whole per-op-type predict on device: raw f32 features in,
     clamped latencies out.
 
     ``reduction`` is the model's ``(kind, scale, bias)`` — GBDT is
     ``("sum", learning_rate, f0)``, RF is ``("mean", 1.0, 0.0)`` — so
-    standardization, traversal, the stage/tree reduction, and the ≥0
-    clamp all run in one device program instead of bouncing a float64
-    (rows × trees) matrix back through the host.
+    traversal, the stage/tree reduction, and the ≥0 clamp all run in
+    one device program instead of bouncing a float64 (rows × trees)
+    matrix back through the host.  ``raw_threshold`` comes from
+    `raw_thresholds` for the model's scaler.
     """
-    if not HAS_JAX:                                       # pragma: no cover
-        raise RuntimeError("jax is unavailable — use the numpy tree backend")
     kind, scale, bias = reduction
-    mean, std = device_scaler
     db = flat.device_bank()
     n = x.shape[0]
-    if backend == "pallas":
-        from repro.kernels.tree_gather_pallas import gather_leaves_pallas
-
-        xd = (db.stage_input(x, sharded=False) - mean) / std
-        vals = gather_leaves_pallas(db, xd)[:n, :db.n_trees]
-        red = jnp.sum(vals, axis=1) if kind == "sum" \
-            else jnp.mean(vals, axis=1)
-        out = jnp.maximum(jnp.float32(bias) + jnp.float32(scale) * red, 0.0)
-    else:
-        out = db.fused(mean, std, jnp.float32(scale), jnp.float32(bias),
-                       db.stage_input(x), kind)[:n]
+    out = db.fused(raw_threshold, jnp.float32(scale), jnp.float32(bias),
+                   db.stage_input(x), kind)[:n]
     return np.asarray(out, dtype=np.float64)

@@ -12,28 +12,20 @@ from typing import Optional, Tuple
 import numpy as np
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """`axis_types` where supported (jax ≥ 0.5); older jax has Auto only."""
-    import jax
-
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """(16,16) data×model single-pod or (2,16,16) pod×data×model multi-pod."""
     import jax
 
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     import jax
 
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def flush_mesh(max_devices: Optional[int] = None):

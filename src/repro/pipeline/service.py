@@ -437,22 +437,22 @@ class LatencyService:
         span = self.obs.tracer.start_span(
             "service.kernel", attrs={"op_type": op_type or "",
                                      "backend": backend, "rows": n_rows})
-        # Device tiers on an unwrapped tree model take the fused path:
+        # The device tier on an unwrapped tree model takes the fused path:
         # standardize → traverse → reduce → clamp in one device program
         # on the resident bank, fed float32 feature matrices with no
         # host float64 bounce.  No backend-knob swap is involved, so
         # concurrent flushes of the same model don't serialize here.
-        # (Calibrated wrappers still resolve device backends — their
+        # (Calibrated wrappers still resolve the device backend — their
         # inner traversal goes through the swap path below and benefits
         # from bank residency, just not from fusion.)
         red_fn = getattr(model, "_device_reduction", None)
-        if (backend in ("jax", "pallas") and group is not None
+        if (backend == "jax" and group is not None
                 and flat_model is model
                 and red_fn is not None and red_fn() is not None):
             ms = [gf.matrix32(op_type) for gf in group]
             x32 = ms[0] if len(ms) == 1 else np.concatenate(ms, axis=0)
             try:
-                preds = model.predict_on_device(x32, backend=backend)
+                preds = model.predict_on_device(x32)
             except BaseException:
                 span.end("error")
                 raise
@@ -536,13 +536,9 @@ class LatencyService:
                 resident["bank_uploads"] += st["uploads"]
                 resident["inputs_staged"] += st["inputs_staged"]
                 resident["sharded_banks"] += int(st["sharded"])
-        out: Dict[str, Any] = dict(resident)
-        try:
-            from repro.kernels.tree_gather import residency_counters
-            out["lifetime"] = residency_counters()
-        except Exception:                             # pragma: no cover
-            pass
-        return out
+        from repro.kernels.tree_gather import residency_counters
+
+        return {**resident, "lifetime": residency_counters()}
 
     def stats(self) -> Dict[str, Any]:
         """Cache counters + which tree backend batched queries ran on

@@ -1,8 +1,8 @@
-"""Multi-device distributed tests (subprocess: 8 host devices).
+"""Multi-device distributed tests (subprocess: 8 or 4 host devices).
 
 The main test process sees 1 device (XLA device count locks at first
 jax import), so sharding/pjit/pipeline tests run in subprocesses with
-XLA_FLAGS=--xla_force_host_platform_device_count=8.
+XLA_FLAGS=--xla_force_host_platform_device_count=N.
 """
 import os
 import subprocess
@@ -13,20 +13,11 @@ import pytest
 
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
-# The sharding/pipeline stack targets the jax>=0.6 mesh APIs
-# (jax.set_mesh, jax.shard_map, AxisType); on older jax the subprocess
-# tests cannot run — skip them rather than fail the tier-1 suite.
-import jax  # noqa: E402
 
-requires_new_sharding = pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax, "shard_map")),
-    reason="needs jax>=0.6 sharding APIs (jax.set_mesh / jax.shard_map)")
-
-
-def run_subprocess(body: str, timeout: int = 420) -> str:
-    code = textwrap.dedent("""
+def run_subprocess(body: str, timeout: int = 420, devices: int = 8) -> str:
+    code = textwrap.dedent(f"""
         import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -40,7 +31,6 @@ def run_subprocess(body: str, timeout: int = 420) -> str:
     return proc.stdout
 
 
-@requires_new_sharding
 def test_sharded_train_step_matches_single_device():
     out = run_subprocess("""
         import dataclasses
@@ -70,7 +60,6 @@ def test_sharded_train_step_matches_single_device():
     assert "LOSS_MATCH" in out
 
 
-@requires_new_sharding
 def test_fsdp_gather_numerics_match_tp():
     out = run_subprocess("""
         import dataclasses
@@ -96,7 +85,6 @@ def test_fsdp_gather_numerics_match_tp():
     assert "FSDP_MATCH" in out
 
 
-@requires_new_sharding
 def test_pipeline_parallel_matches_sequential():
     out = run_subprocess("""
         from functools import partial
@@ -130,7 +118,6 @@ def test_pipeline_parallel_matches_sequential():
     assert "PIPELINE_MATCH" in out
 
 
-@requires_new_sharding
 def test_compressed_psum_under_shard_map():
     out = run_subprocess("""
         from jax.sharding import PartitionSpec as P
@@ -164,7 +151,6 @@ def test_elastic_mesh_shapes():
     assert int(np.prod(shape)) == 24
 
 
-@requires_new_sharding
 def test_elastic_recovery_roundtrip(tmp_path):
     out = run_subprocess(f"""
         from repro.checkpoint import CheckpointManager
@@ -191,7 +177,6 @@ def test_elastic_recovery_roundtrip(tmp_path):
     assert "ELASTIC_OK" in out
 
 
-@requires_new_sharding
 def test_dryrun_reduced_cell_on_8_devices():
     """End-to-end mini dry-run: reduced arch on a small mesh, full record."""
     out = run_subprocess("""
@@ -210,20 +195,8 @@ def test_dryrun_reduced_cell_on_8_devices():
     assert "DRYRUN_OK" in out
 
 
-# Flush sharding only needs the stable jax.experimental.shard_map (old
-# API), so unlike the mesh-API tests above it runs on this container.
-try:
-    from jax.experimental.shard_map import shard_map as _sm  # noqa: F401
-    _HAS_SHARD_MAP = True
-except Exception:                                 # pragma: no cover
-    _HAS_SHARD_MAP = False
-
-requires_shard_map = pytest.mark.skipif(
-    not _HAS_SHARD_MAP, reason="needs jax.experimental.shard_map")
-
-
-@requires_shard_map
-def test_sharded_tree_flush_matches_numpy_and_is_deterministic():
+@pytest.mark.parametrize("devices", [8, 4])
+def test_sharded_tree_flush_matches_numpy_and_is_deterministic(devices):
     """Giant flushes shard rows across the device mesh; reassembly is
     row-order deterministic and bank upload stays at one."""
     out = run_subprocess("""
@@ -233,7 +206,7 @@ def test_sharded_tree_flush_matches_numpy_and_is_deterministic():
         x = np.abs(rng.standard_normal((200, 8))) * np.linspace(1, 30, 8)
         y = x @ rng.random(8) + 0.1
         m = GBDTPredictor(n_stages=20).fit(x, y)
-        # 2050 rows: above SHARD_MIN_ROWS and not a multiple of the 8
+        # 2050 rows: above SHARD_MIN_ROWS and not a multiple of 8 or 4
         # forced host devices, so the pad-and-slice path is exercised.
         q = np.abs(rng.standard_normal((2050, 8))) * np.linspace(1, 30, 8)
         flat = m.flat()
@@ -242,6 +215,7 @@ def test_sharded_tree_flush_matches_numpy_and_is_deterministic():
         got = flat.predict_trees(xs, backend="jax")
         db = flat.device_bank()
         assert db.mesh is not None and db.stats()["sharded"]
+        assert db.mesh.devices.size == len(jax.devices())
         assert got.shape == ref.shape
         assert np.allclose(got, ref, rtol=1e-4, atol=1e-7)
         again = flat.predict_trees(xs, backend="jax")
@@ -255,5 +229,5 @@ def test_sharded_tree_flush_matches_numpy_and_is_deterministic():
         small = flat.predict_trees(xs[:64], backend="jax")
         assert np.allclose(small, ref[:64], rtol=1e-4, atol=1e-7)
         print("SHARDED_FLUSH_OK")
-    """)
+    """, devices=devices)
     assert "SHARDED_FLUSH_OK" in out

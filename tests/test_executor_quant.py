@@ -1,9 +1,11 @@
 """Executor modes (op-by-op / fused / whole-jit) and the int8 path."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.executor import GraphExecutor, winograd_conv2d, winograd_transform_weights
+from repro.core.executor import (GraphExecutor, JitOp, build_op_fn, make_array,
+                                 winograd_conv2d, winograd_transform_weights)
 from repro.core.nas_space import NASSpaceConfig, sample_architecture
 from repro.core.realworld import REALWORLD
 from repro.quant.int8 import ACT_SCALE, dequantize, quantize_symmetric, rescale_int8
@@ -19,6 +21,26 @@ def test_modes_numerically_equivalent():
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(outs["op_by_op"], outs["whole_jit"],
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("op_type", ["conv2d", "fully_connected", "split"])
+def test_op_program_takes_weights_as_arguments(op_type):
+    g = sample_architecture(1, NASSpaceConfig(resolution=16))
+    node = next(n for n in g.nodes if n.op_type == op_type)
+    fn, in_ids = build_op_fn(g, node)
+    xs = [jnp.asarray(make_array(g.tensor(t).shape, "float32", 3 + i, 1.0))
+          for i, t in enumerate(in_ids)]
+    op = JitOp(fn)
+    for a, b in zip(jax.tree.leaves(op(*xs)), jax.tree.leaves(fn(*xs))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+    # The weights are inputs of the compiled program, not constants in
+    # it, so executables do not grow with the model.
+    weights = op._run.args[0]
+    assert bool(weights) == (op_type != "split")
+    compiled = op._run.func.lower(weights, *xs).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes >= sum(
+        a.nbytes for a in [*weights, *xs])
 
 
 def test_fused_mode_reduces_kernel_count():

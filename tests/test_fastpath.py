@@ -329,7 +329,7 @@ class TestDeviceResidency:
         old = m.flat()._device_bank
         assert old is not None
         m.fit(x, y + 1.0)                 # retrain → flat (and bank) drop
-        assert m._flat is None and m._device_scaler is None
+        assert m._flat is None and m._device_thresholds is None
         m.flat().predict_trees(m.scaler.transform(x), backend="jax")
         assert m.flat()._device_bank is not old
 
@@ -343,6 +343,28 @@ class TestDeviceResidency:
             np.testing.assert_allclose(dev, host, rtol=1e-3, atol=1e-5)
             assert (dev >= 0).all()
 
+    @pytest.mark.parametrize("seed", range(3))
+    def test_device_routes_exact_ties_like_host(self, seed):
+        # Trained on even values, queried on all: odd queries sit exactly
+        # on split midpoints, where the float64 host's choice is a matter
+        # of rounding.  Column 1 is FLOP-sized and clustered.  Both are
+        # float32-representable, so the device must route every row as
+        # the host does: only the float32 leaf sum may differ.
+        rng = np.random.default_rng(seed)
+
+        def feats(k):
+            k = k.astype(np.float64)
+            return np.column_stack([k[:, 0], 2.0 ** 31 + 2.0 ** 20 * k[:, 1],
+                                    k[:, 2]])
+
+        x = feats(2 * rng.integers(1, 32, (300, 3)))
+        y = x[:, 0] * 1e-6 + (x[:, 1] - 2.0 ** 31) * 1e-12 + x[:, 2] * 2e-6
+        m = GBDTPredictor(n_stages=30).fit(x, y)
+        q = feats(rng.integers(2, 64, (2000, 3)))
+        host = m.predict(q)
+        dev = m.predict_on_device(q.astype(np.float32))
+        np.testing.assert_allclose(dev, host, rtol=1e-5, atol=1e-12)
+
     def test_device_stats_lazy(self):
         pytest.importorskip("jax")
         x, y = _data()
@@ -355,38 +377,18 @@ class TestDeviceResidency:
 
 
 class TestBackendTiers:
-    def test_resolve_three_tiers(self, monkeypatch):
-        pytest.importorskip("jax")
+    def test_resolve_three_tiers(self):
         from repro.core.predictors import flat as flat_mod
 
-        monkeypatch.setattr(flat_mod, "_pallas_available", lambda: True)
         assert flat_mod.resolve_backend("auto", 100) == "numpy"
         assert flat_mod.resolve_backend(
+            "auto", flat_mod.AUTO_JAX_MIN_SLOTS - 1) == "numpy"
+        assert flat_mod.resolve_backend(
             "auto", flat_mod.AUTO_JAX_MIN_SLOTS) == "jax"
-        assert flat_mod.resolve_backend(
-            "auto", flat_mod.AUTO_PALLAS_MIN_SLOTS) == "pallas"
+        assert flat_mod.resolve_backend("auto", 1 << 24) == "jax"
         # Explicit backends pass through untouched.
-        for b in ("numpy", "jax", "pallas"):
+        for b in ("numpy", "jax"):
             assert flat_mod.resolve_backend(b, 1) == b
-
-    def test_pallas_tier_needs_compiled_backend(self, monkeypatch):
-        pytest.importorskip("jax")
-        from repro.core.predictors import flat as flat_mod
-
-        monkeypatch.setattr(flat_mod, "_pallas_available", lambda: False)
-        # Without a compiled Pallas backend the top tier degrades to jax
-        # rather than serving through interpret mode.
-        assert flat_mod.resolve_backend(
-            "auto", flat_mod.AUTO_PALLAS_MIN_SLOTS) == "jax"
-
-    def test_pallas_available_env_override(self, monkeypatch):
-        pytest.importorskip("jax")
-        from repro.core.predictors.flat import _pallas_available
-
-        monkeypatch.delenv("REPRO_AUTO_PALLAS", raising=False)
-        assert _pallas_available() is False     # CPU container: no TPU
-        monkeypatch.setenv("REPRO_AUTO_PALLAS", "1")
-        assert _pallas_available() is True
 
     def test_auto_resolving_to_numpy_is_bit_exact(self):
         # "auto" must never silently change reports when it resolves to
@@ -407,7 +409,6 @@ class TestBackendTiers:
         runs = auto_svc.stats()["backend_runs"]
         assert runs.get("numpy", 0) > 0          # the tier actually ran
         assert runs.get("jax", 0) == 0
-        assert runs.get("pallas", 0) == 0
 
 
 class TestServiceDevicePath:

@@ -12,8 +12,8 @@ the invariants the rest of the stack leans on:
     (element-wise tails add no flops, merged bytes never exceed the
     parts, each merge saves one kernel launch);
   * `fuse_graph` is idempotent — re-fusing a fused graph is a no-op;
-  * featurize→predict parity: "jax" and "pallas" service backends agree
-    tightly, "numpy" (float64 trees) agrees within tolerance.
+  * featurize→predict parity: the "jax" service backend (float32 trees)
+    agrees with "numpy" (float64 trees) within tolerance.
 
 Profiles: "dev" (default, small; keeps tier-1 fast) and "ci"
 (HYPOTHESIS_PROFILE=ci; ≥500 examples total, derandomized so a CI
@@ -211,7 +211,7 @@ def backend_services():
     hub.train(store, SOURCE, "gbdt", hparams={"n_stages": 20}, min_samples=3)
     return {b: LatencyService(hub, default_setting=SOURCE, predictor="gbdt",
                               inference_backend=b)
-            for b in ("numpy", "jax", "pallas")}
+            for b in ("numpy", "jax")}
 
 
 @given(gt=rw_genotypes())
@@ -221,9 +221,6 @@ def test_predict_parity_across_backends(backend_services, gt):
     g = decode_genotype(gt, SPACE)
     e2e = {b: svc.predict_batch([g])[0].e2e_s
            for b, svc in backend_services.items()}
-    # jax and pallas(interpret) run the same f32 tree math; only the
-    # accumulation order differs, so agreement is tight but not bitwise.
-    assert e2e["pallas"] == pytest.approx(e2e["jax"], rel=1e-6, abs=1e-12)
     # numpy trees run in f64; near-tie splits may route differently, so
     # the end-to-end sums agree within tolerance rather than bitwise.
     assert e2e["numpy"] == pytest.approx(e2e["jax"], rel=0.02, abs=1e-6)

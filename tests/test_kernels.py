@@ -1,4 +1,4 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret) vs ref.py oracles."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret) and jax kernels vs oracles."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -96,85 +96,28 @@ class TestWinogradConv:
         assert t.shape == (2 * 5 * 5, 4, 4, 4)
 
 
-class TestTreeGatherPallas:
-    """Pallas tree-gather (interpret on CPU) vs the numpy oracle.
+class TestTreeGather:
+    """jax tree gather (`repro.kernels.tree_gather`) vs the numpy oracle.
 
-    Shapes chosen to hit the padding paths: rows not a block multiple,
-    trees far from the 128-lane pad, single-row / single-tree banks.
+    Shapes reach the traversal's edges: depth 1 (the peeled root level
+    alone, no loop step), single-row and single-tree banks, and row
+    counts that are not a multiple of anything.
     """
-
-    def _fit(self, n_trees, depth=3, n=120, d=5, seed=0):
-        from repro.core.predictors import GBDTPredictor
-
-        rng = np.random.default_rng(seed)
-        x = np.abs(rng.standard_normal((n, d))) * np.linspace(1, 20, d)
-        y = x @ rng.random(d) + 0.1
-        m = GBDTPredictor(n_stages=n_trees, max_depth=depth).fit(x, y)
-        return m, rng
 
     @pytest.mark.parametrize("rows,trees,depth", [
         (1, 1, 1), (7, 3, 2), (64, 10, 3), (257, 20, 4), (300, 130, 2),
     ])
     def test_matches_numpy_oracle(self, rows, trees, depth):
-        from repro.kernels.tree_gather_pallas import predict_trees_pallas
+        from repro.core.predictors import GBDTPredictor
 
-        m, rng = self._fit(trees, depth=depth, seed=rows + trees)
+        rng = np.random.default_rng(rows + trees)
+        x = np.abs(rng.standard_normal((120, 5))) * np.linspace(1, 20, 5)
+        y = x @ rng.random(5) + 0.1
+        m = GBDTPredictor(n_stages=trees, max_depth=depth).fit(x, y)
         q = np.abs(rng.standard_normal((rows, 5))) * np.linspace(1, 20, 5)
         flat = m.flat()
         xs = m.scaler.transform(q)
         want = flat.predict_trees(xs, backend="numpy")
-        got = predict_trees_pallas(flat, xs)
+        got = flat.predict_trees(xs, backend="jax")
         assert got.shape == want.shape == (rows, trees)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-7)
-
-    def test_matches_jax_backend_bitwise(self):
-        # Both device tiers run float32 with the same `xv <= thr`
-        # compare form, so they agree exactly, not just approximately.
-        m, rng = self._fit(16, seed=7)
-        q = np.abs(rng.standard_normal((200, 5))) * np.linspace(1, 20, 5)
-        flat = m.flat()
-        xs = m.scaler.transform(q)
-        jx = flat.predict_trees(xs, backend="jax")
-        pls = flat.predict_trees(xs, backend="pallas")
-        assert np.array_equal(jx, pls)
-
-    def test_block_size_invariance(self):
-        from repro.kernels.tree_gather_pallas import predict_trees_pallas
-
-        m, rng = self._fit(12, seed=3)
-        q = np.abs(rng.standard_normal((513, 5))) * np.linspace(1, 20, 5)
-        xs = m.scaler.transform(q)
-        a = predict_trees_pallas(m.flat(), xs, block_rows=128)
-        b = predict_trees_pallas(m.flat(), xs, block_rows=512)
-        assert np.array_equal(a, b)
-
-    def test_vmem_guard_raises_for_oversized_cell(self):
-        from repro.kernels import tree_gather_pallas as tgp
-
-        m, rng = self._fit(4, seed=5)
-        xs = m.scaler.transform(
-            np.abs(rng.standard_normal((16, 5))) * np.linspace(1, 20, 5))
-        db = m.flat().device_bank()
-        xd = db.stage_input(xs, sharded=False)
-        real = tgp.VMEM_BUDGET_BYTES
-        try:
-            tgp.VMEM_BUDGET_BYTES = 1024
-            with pytest.raises(ValueError, match="VMEM budget"):
-                tgp.gather_leaves_pallas(db, xd)
-        finally:
-            tgp.VMEM_BUDGET_BYTES = real
-
-    def test_reuses_resident_bank(self):
-        from repro.kernels.tree_gather_pallas import predict_trees_pallas
-
-        m, rng = self._fit(8, seed=11)
-        q = np.abs(rng.standard_normal((32, 5))) * np.linspace(1, 20, 5)
-        flat = m.flat()
-        xs = m.scaler.transform(q)
-        predict_trees_pallas(flat, xs)
-        db = flat._device_bank
-        assert db is not None and db.uploads == 1
-        predict_trees_pallas(flat, xs)
-        # Same bank object, still one upload: the padded pallas view is
-        # derived on-device and cached, never re-transferred.
-        assert flat._device_bank is db and db.uploads == 1

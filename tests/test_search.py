@@ -369,9 +369,6 @@ class TestServiceBackend:
         assert st["backend_runs"].get("jax", 0) == 0
 
     def test_auto_crosses_to_jax_at_threshold(self, served, monkeypatch):
-        from repro.kernels.tree_gather import HAS_JAX
-        if not HAS_JAX:
-            pytest.skip("jax gather backend unavailable")
         import repro.core.predictors.flat as flat_mod
         monkeypatch.setattr(flat_mod, "AUTO_JAX_MIN_SLOTS", 1)
         svc = LatencyService(served["hub"], default_setting=SOURCE,
