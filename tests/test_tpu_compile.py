@@ -2,7 +2,7 @@
 
 Nothing runs: each case lowers and compiles a program for a `v5e:2x2`
 topology that is described, not attached, so what the chip's compiler
-would refuse fails here first (on-chip-measurement guide, section 2).
+would refuse fails here first, on a host without a TPU.
 
 Cases: the fused tree traversal that serves flushes, at the chip
 smoke's bank size and at a larger one; every distinct op kind of
