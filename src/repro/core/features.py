@@ -578,6 +578,12 @@ def graph_features(graph: OpGraph, *, cache: bool = True,
     return gf
 
 
+def graph_features_cached(graph: OpGraph) -> bool:
+    """True when `graph_features` would answer ``graph`` from the cache
+    (a membership test: it does not touch the entry's recency)."""
+    return graph.fingerprint() in _GRAPH_FEATURE_CACHE
+
+
 def graph_feature_cache_info() -> Dict[str, int]:
     return dict(_GRAPH_FEATURE_CACHE.info())
 

@@ -229,13 +229,15 @@ class FlattenedTreeModel:
         """
         return None
 
-    def predict_on_device(self, x: np.ndarray) -> np.ndarray:
+    def predict_on_device(self, x: np.ndarray,
+                          tracer: Optional[Any] = None) -> np.ndarray:
         """Raw (unstandardized) float32 features → clamped predictions,
         with traverse/reduce on-device against thresholds folded into
         raw feature units (no float64 (rows × trees) bounce through the
         host).  Float32 end-to-end;
         `LatencyService` only routes here when `resolve_backend` already
-        picked the device tier.
+        picked the device tier.  A ``tracer`` records the call's stage,
+        dispatch and wait spans (`tree_gather.fused_predict`).
         """
         red = self._device_reduction()
         if red is None:
@@ -246,7 +248,12 @@ class FlattenedTreeModel:
         if self._device_thresholds is None:
             self._device_thresholds = tg.raw_thresholds(self.flat(),
                                                         self.scaler)
-        return tg.fused_predict(self.flat(), self._device_thresholds, red, x)
+        args = (self.flat(), self._device_thresholds, red, x)
+        if tracer is None:
+            # The four-argument form is the one the benchmark's
+            # planted-fault doubles of `fused_predict` take.
+            return tg.fused_predict(*args)
+        return tg.fused_predict(*args, tracer=tracer)
 
     def device_stats(self) -> Optional[Dict[str, Any]]:
         """Residency snapshot of this model's bank, or None if nothing

@@ -34,8 +34,8 @@ numpy backend stays the bit-exact default; the device tier is opt-in
 from __future__ import annotations
 
 import threading
-from functools import partial
-from typing import Any, Dict, Tuple
+from functools import partial, wraps
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,12 +43,16 @@ import numpy as np
 from jax import lax
 
 from repro.launch.mesh import flush_mesh
+from repro.obs.tracing import Tracer
 
 # Lifetime counters (survive bank invalidation — `DeviceBank` instances
 # die with their FlatEnsemble, these do not).  `LatencyService.stats()`
 # reports both views: what is resident now and what was ever uploaded.
+# ``programs_traced`` counts traversal programs traced for a new shape
+# or mesh, each of which is then compiled or fetched from the
+# persistent cache.
 _COUNTERS = {"banks_built": 0, "bank_bytes": 0, "inputs_staged": 0,
-             "input_bytes": 0}
+             "input_bytes": 0, "programs_traced": 0}
 _COUNTERS_LOCK = threading.Lock()
 
 # Flushes below this many rows skip mesh sharding: the all-gather +
@@ -97,8 +101,26 @@ def _fused_core(feature, raw_threshold, left, right, value, roots,
     return jnp.maximum(bias + scale * red, 0.0)           # Predictor.predict clamp
 
 
-_traverse = jax.jit(_traverse_core, static_argnames=("depth",))
-_fused = jax.jit(_fused_core, static_argnames=("depth", "kind"))
+def _entry(core):
+    """``core`` as a jitted program's entry point: counts one traced
+    program each time JAX traces it.  The count is a trace-time side
+    effect, so a cached program's call costs nothing; it sits on the
+    entry and not in the shared bodies (`_fused_core` calls
+    `_traverse_core`).  `wraps` keeps the XLA module name
+    ``jit_<core name>`` that the device-trace readers match."""
+    @wraps(core)
+    def traced(*args, **kwargs):
+        _count(programs_traced=1)
+        return core(*args, **kwargs)
+    return traced
+
+
+_traverse_entry = _entry(_traverse_core)
+_fused_entry = _entry(_fused_core)
+_traverse = jax.jit(_traverse_entry, static_argnames=("depth",))
+_fused = jax.jit(_fused_entry, static_argnames=("depth", "kind"))
+# Stands in for the tracer of an untraced call: its spans are no-ops.
+_UNTRACED = Tracer(enabled=False)
 
 
 class DeviceBank:
@@ -206,7 +228,7 @@ class DeviceBank:
         """(rows, trees) leaf values for staged rows ``xd`` (device)."""
         if self.mesh is not None and _row_sharded(xd):
             fn = self._sharded_fn(("traverse", self.depth),
-                                  partial(_traverse_core, depth=self.depth),
+                                  partial(_traverse_entry, depth=self.depth),
                                   out_rank2=True)
             return fn(*self.bank_args, xd)
         return _traverse(*self.bank_args, xd, depth=self.depth)
@@ -217,7 +239,7 @@ class DeviceBank:
                 self.value, self.roots, scale, bias, xd)
         if self.mesh is not None and _row_sharded(xd):
             fn = self._sharded_fn(("fused", self.depth, kind),
-                                  partial(_fused_core, depth=self.depth,
+                                  partial(_fused_entry, depth=self.depth,
                                           kind=kind),
                                   out_rank2=False)
             return fn(*args)
@@ -298,8 +320,8 @@ def raw_thresholds(flat, scaler):
     return raw
 
 
-def fused_predict(flat, raw_threshold, reduction: Tuple,
-                  x: np.ndarray) -> np.ndarray:
+def fused_predict(flat, raw_threshold, reduction: Tuple, x: np.ndarray,
+                  tracer: Optional[Tracer] = None) -> np.ndarray:
     """Whole per-op-type predict on device: raw f32 features in,
     clamped latencies out.
 
@@ -309,10 +331,24 @@ def fused_predict(flat, raw_threshold, reduction: Tuple,
     one device program instead of bouncing a float64 (rows × trees)
     matrix back through the host.  ``raw_threshold`` comes from
     `raw_thresholds` for the model's scaler.
+
+    With an enabled ``tracer`` the call records three spans, children
+    of the caller's ambient span: ``tree.stage`` (the float32 batch and
+    its transfer; attrs ``rows``, ``bytes``), ``tree.dispatch`` (the
+    program and the slice are enqueued) and ``tree.wait`` (the host
+    blocks on the readback).
     """
     kind, scale, bias = reduction
+    tracer = _UNTRACED if tracer is None else tracer
     db = flat.device_bank()
     n = x.shape[0]
-    out = db.fused(raw_threshold, jnp.float32(scale), jnp.float32(bias),
-                   db.stage_input(x), kind)[:n]
-    return np.asarray(out, dtype=np.float64)
+    with tracer.span("tree.stage") as sp:
+        xd = db.stage_input(x)
+        if tracer.enabled:
+            sp.set_attr("rows", n)
+            sp.set_attr("bytes", int(xd.nbytes))
+    with tracer.span("tree.dispatch"):
+        out = db.fused(raw_threshold, jnp.float32(scale), jnp.float32(bias),
+                       xd, kind)[:n]
+    with tracer.span("tree.wait"):
+        return np.asarray(out, dtype=np.float64)

@@ -43,7 +43,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from repro.core.composition import PredictorBank
-from repro.core.features import graph_features
+from repro.core.features import graph_features, graph_features_cached
 from repro.core.predictors.flat import resolve_backend
 from repro.core.fusion import fuse_graph
 from repro.core.ir import OpGraph
@@ -255,10 +255,15 @@ class LatencyService:
         skey = setting_key(setting)
         out: List[Optional[PredictionReport]] = [None] * len(graphs)
         fresh: List[Tuple[int, str, OpGraph]] = []   # (position, fp, graph)
+        tracer = self.obs.tracer
         # Fingerprinting mutates the graph's memo slot — do it outside
         # the lock (graphs are caller-owned; the cache/counters aren't).
-        fps = [g.fingerprint() for g in graphs]
-        span = self.obs.tracer.start_span(
+        # Its span closes before `service.predict_batch` opens, so the
+        # latter keeps the extent it always had.
+        with tracer.span("service.fingerprint") as fp_span:
+            fps = [g.fingerprint() for g in graphs]
+            fp_span.set_attr("graphs", len(graphs))
+        span = tracer.start_span(
             "service.predict_batch",
             attrs={"setting": skey, "family": family, "graphs": len(graphs)})
         with self._lock:
@@ -283,8 +288,9 @@ class LatencyService:
             span.end()
             return out  # type: ignore[return-value]
         try:
-            return self._predict_fresh(graphs, setting, family, skey,
-                                       out, fresh, bank_version, span)
+            with tracer.activate(span):
+                return self._predict_fresh(graphs, setting, family, skey,
+                                           out, fresh, bank_version, span)
         except BaseException:
             span.end("error")
             raise
@@ -298,24 +304,30 @@ class LatencyService:
         """The uncached tail of `predict_batch` (split out so the span
         around it ends exactly once on every exit path)."""
         bank, bank_epoch = self._bank(setting, family)
-        # Fused-mode scenarios are profiled (and therefore predicted) on
-        # the fused graph — same rewrite GraphExecutor applies.
-        exec_graphs = []
-        for i, fp, g in fresh:
-            exec_graphs.append(fuse_graph(g)[1] if setting.is_gpu_like else g)
+        tracer = self.obs.tracer
+        with tracer.span("service.featurize") as feat_span:
+            # Fused-mode scenarios are profiled (and therefore predicted)
+            # on the fused graph — same rewrite GraphExecutor applies.
+            exec_graphs = [fuse_graph(g)[1] if setting.is_gpu_like else g
+                           for _, _, g in fresh]
+            if tracer.enabled:
+                feat_span.set_attr("graphs", len(exec_graphs))
+                feat_span.set_attr("computed", sum(
+                    not graph_features_cached(g) for g in exec_graphs))
 
-        # Gather feature matrices grouped by op type across every fresh
-        # graph.  `graph_features` memoizes per fingerprint, so a graph
-        # the process has seen before (NAS re-scoring after a cache
-        # clear, retraining) contributes without re-running featurizers.
-        gfs: Dict[str, List[Any]] = {}          # op_type → GraphFeatures refs
-        slots: Dict[str, List[Tuple[int, int]]] = {}  # op_type → (fresh idx, node idx)
-        for j, g in enumerate(exec_graphs):
-            gf = graph_features(g)
-            for op_type in gf.matrix:
-                gfs.setdefault(op_type, []).append(gf)
-                slots.setdefault(op_type, []).extend(
-                    (j, int(k)) for k in gf.index[op_type])
+            # Gather feature matrices grouped by op type across every
+            # fresh graph.  `graph_features` memoizes per fingerprint, so
+            # a graph the process has seen before (NAS re-scoring after a
+            # cache clear, retraining) contributes without re-running
+            # featurizers.
+            gfs: Dict[str, List[Any]] = {}      # op_type → GraphFeatures refs
+            slots: Dict[str, List[Tuple[int, int]]] = {}  # op_type → (fresh idx, node idx)
+            for j, g in enumerate(exec_graphs):
+                gf = graph_features(g)
+                for op_type in gf.matrix:
+                    gfs.setdefault(op_type, []).append(gf)
+                    slots.setdefault(op_type, []).extend(
+                        (j, int(k)) for k in gf.index[op_type])
 
         # One predictor call per op type; unseen types contribute 0
         # (same fallback as PredictorBank.predict_op).  `_run_model`
@@ -333,24 +345,27 @@ class LatencyService:
             for (j, k), p in zip(slots[op_type], preds):
                 per_op[j][k] = (op_type, float(p))
 
-        for (i, fp, g), eg, ops in zip(fresh, exec_graphs, per_op):
-            overhead = bank.overhead + bank.overhead_per_kernel * len(eg.nodes)
-            total = overhead + bank.op_sum_scale * sum(p for _, p in ops)
-            report = PredictionReport(
-                graph_name=g.name, fingerprint=fp, setting=skey,
-                predictor=family, e2e_s=float(total),
-                per_op=tuple(ops), overhead_s=float(overhead),
-                num_ops=g.num_ops(), num_kernels=len(eg.nodes),
-                bank_epoch=bank_epoch,
-            )
-            with self._lock:
-                # Don't poison a cache another thread just cleared on a
-                # retrain: this report was computed against the bank
-                # version snapshotted above, so it only enters the cache
-                # while that version is still current.
-                if self._hub_version == bank_version:
-                    self._insert((fp, skey, family), report)
-            out[i] = report
+        with tracer.span("service.assemble") as asm_span:
+            for (i, fp, g), eg, ops in zip(fresh, exec_graphs, per_op):
+                overhead = (bank.overhead
+                            + bank.overhead_per_kernel * len(eg.nodes))
+                total = overhead + bank.op_sum_scale * sum(p for _, p in ops)
+                report = PredictionReport(
+                    graph_name=g.name, fingerprint=fp, setting=skey,
+                    predictor=family, e2e_s=float(total),
+                    per_op=tuple(ops), overhead_s=float(overhead),
+                    num_ops=g.num_ops(), num_kernels=len(eg.nodes),
+                    bank_epoch=bank_epoch,
+                )
+                with self._lock:
+                    # Don't poison a cache another thread just cleared on
+                    # a retrain: this report was computed against the
+                    # bank version snapshotted above, so it only enters
+                    # the cache while that version is still current.
+                    if self._hub_version == bank_version:
+                        self._insert((fp, skey, family), report)
+                out[i] = report
+            asm_span.set_attr("reports", len(fresh))
         span.end()
         return out  # type: ignore[return-value]
 
@@ -451,8 +466,11 @@ class LatencyService:
                 and red_fn is not None and red_fn() is not None):
             ms = [gf.matrix32(op_type) for gf in group]
             x32 = ms[0] if len(ms) == 1 else np.concatenate(ms, axis=0)
+            tracer = self.obs.tracer
             try:
-                preds = model.predict_on_device(x32)
+                with tracer.activate(span):
+                    preds = model.predict_on_device(
+                        x32, tracer=tracer if tracer.enabled else None)
             except BaseException:
                 span.end("error")
                 raise

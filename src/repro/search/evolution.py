@@ -41,6 +41,7 @@ import numpy as np
 from repro.core.nas_space import (Genotype, NASSpaceConfig, RandomWiredConfig,
                                   genotype_from_json)
 from repro.core.profiler import DeviceSetting, ProfileSession
+from repro.obs import Observability
 from repro.search import encoding
 from repro.search.objectives import DeviceBudget, LatencyScorer, make_quality
 from repro.search.pareto import (ParetoFront, crowding_distance,
@@ -239,8 +240,13 @@ class SearchEngine:
 
     def __init__(self, service: Any, budgets: Sequence[DeviceBudget],
                  config: Optional[SearchConfig] = None, *,
-                 predictor: Optional[str] = None):
+                 predictor: Optional[str] = None,
+                 obs: Optional[Observability] = None):
         self.cfg = config or SearchConfig()
+        # The service's bundle by default, so the engine's phase spans
+        # and the service's spans land in one tracer.
+        self.obs = (obs or getattr(service, "obs", None)
+                    or Observability.quiet())
         self.space = self.cfg.space()
         self.scorer = LatencyScorer(service, budgets, predictor)
         self.quality_fn = make_quality(self.cfg.quality)
@@ -283,21 +289,26 @@ class SearchEngine:
         new = [d for d in dict.fromkeys(digests) if d not in self.memo]
         if not new:
             return 0, 0, 0
-        graphs = [encoding.decode(self.genotypes[d], self.space) for d in new]
-        lats = self.scorer.score(graphs)
-        feas = self.scorer.feasible_mask(lats)
-        viol = self.scorer.violation(lats)
+        tracer = self.obs.tracer
+        with tracer.span("evolution.decode"):
+            graphs = [encoding.decode(self.genotypes[d], self.space)
+                      for d in new]
+        with tracer.span("evolution.score"):
+            lats = self.scorer.score(graphs)
+            feas = self.scorer.feasible_mask(lats)
+            viol = self.scorer.violation(lats)
         # Genotype-scored proxies (SupernetQuality: weight sharing is
         # defined over knobs, not the flat op list) take the genotype.
         on_genotype = getattr(self.quality_fn, "needs_genotype", False)
-        for i, d in enumerate(new):
-            q_arg = self.genotypes[d] if on_genotype else graphs[i]
-            self.memo[d] = {
-                "lat": {k: float(lats[k][i]) for k in self.scorer.keys},
-                "quality": float(self.quality_fn(q_arg)),
-                "feasible": bool(feas[i]),
-                "violation": float(viol[i]),
-            }
+        with tracer.span("evolution.quality"):
+            for i, d in enumerate(new):
+                q_arg = self.genotypes[d] if on_genotype else graphs[i]
+                self.memo[d] = {
+                    "lat": {k: float(lats[k][i]) for k in self.scorer.keys},
+                    "quality": float(self.quality_fn(q_arg)),
+                    "feasible": bool(feas[i]),
+                    "violation": float(viol[i]),
+                }
         return len(new), len(self.scorer.budgets), int(np.sum(feas))
 
     # -- parent selection -----------------------------------------------------
@@ -339,37 +350,57 @@ class SearchEngine:
 
     # -- the loop -------------------------------------------------------------
     def step(self) -> GenStats:
-        """One generation (generation 0 seeds the population)."""
+        """One generation (generation 0 seeds the population).
+
+        Traced as ``evolution.step`` (attrs ``gen``, ``produced``,
+        ``new_scored``) with a child span per phase: ``evolution.select``,
+        ``evolution.breed``, `_ensure_scored`'s ``evolution.decode``,
+        ``evolution.score`` and ``evolution.quality``, then
+        ``evolution.update``."""
         t0 = time.perf_counter()
-        if self.generation == 0 and not self.population:
-            while len(self.population) < self.cfg.population_size:
-                gt = self._seed_genotype()
-                self.population.append(self._register(gt))
-            produced = list(self.population)
-        else:
-            fitness = self._selection_order()
-            children: List[str] = []
-            for _ in range(self.cfg.children_per_gen):
-                if (len(self.population) >= 2
-                        and self.rng.random() < self.cfg.crossover_prob):
-                    a = self.genotypes[self._tournament(fitness)]
-                    b = self.genotypes[self._tournament(fitness)]
-                    child = encoding.crossover(a, b, self.rng, self.space)
-                    child = encoding.mutate(child, self.rng, self.space)
-                else:
-                    parent = self.genotypes[self._tournament(fitness)]
-                    child = encoding.mutate(parent, self.rng, self.space)
-                children.append(self._register(child))
-            produced = children
-        new_scored, predict_calls, feasible_new = self._ensure_scored(produced)
-        for d in dict.fromkeys(produced):
-            if self.memo[d]["feasible"]:
-                self.front.add(d, self._objectives(d))
-        if self.generation > 0:
-            self.population.extend(produced)
-            overflow = len(self.population) - self.cfg.population_size
-            if overflow > 0:
-                del self.population[:overflow]     # age out the oldest
+        tracer = self.obs.tracer
+        with tracer.span("evolution.step") as span:
+            if self.generation == 0 and not self.population:
+                with tracer.span("evolution.breed"):
+                    while len(self.population) < self.cfg.population_size:
+                        gt = self._seed_genotype()
+                        self.population.append(self._register(gt))
+                produced = list(self.population)
+            else:
+                with tracer.span("evolution.select"):
+                    fitness = self._selection_order()
+                with tracer.span("evolution.breed"):
+                    children: List[str] = []
+                    for _ in range(self.cfg.children_per_gen):
+                        if (len(self.population) >= 2 and
+                                self.rng.random() < self.cfg.crossover_prob):
+                            a = self.genotypes[self._tournament(fitness)]
+                            b = self.genotypes[self._tournament(fitness)]
+                            child = encoding.crossover(a, b, self.rng,
+                                                       self.space)
+                            child = encoding.mutate(child, self.rng,
+                                                    self.space)
+                        else:
+                            parent = self.genotypes[self._tournament(fitness)]
+                            child = encoding.mutate(parent, self.rng,
+                                                    self.space)
+                        children.append(self._register(child))
+                produced = children
+            new_scored, predict_calls, feasible_new = self._ensure_scored(
+                produced)
+            with tracer.span("evolution.update"):
+                for d in dict.fromkeys(produced):
+                    if self.memo[d]["feasible"]:
+                        self.front.add(d, self._objectives(d))
+                if self.generation > 0:
+                    self.population.extend(produced)
+                    overflow = len(self.population) - self.cfg.population_size
+                    if overflow > 0:
+                        del self.population[:overflow]   # age out the oldest
+            if tracer.enabled:
+                span.set_attr("gen", self.generation)
+                span.set_attr("produced", len(produced))
+                span.set_attr("new_scored", new_scored)
         best_q = best_lat = None
         if len(self.front):
             pts = self.front.objectives()

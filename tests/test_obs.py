@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from repro.core.dataset import synthetic_graphs
+from repro.core.features import clear_graph_feature_cache
 from repro.core.nas_space import NASSpaceConfig, sample_architecture
 from repro.core.profiler import DeviceSetting, ProfileSession
 from repro.obs import (DEFAULT_SIZE_BUCKETS, DriftMonitor, FlightRecorder,
@@ -591,6 +592,9 @@ class TestFloodConservation:
 
 class TestDeterministicReplay:
     def run_once(self):
+        # The process-wide feature cache is part of the replayed state:
+        # `service.featurize` records how many graphs it featurized.
+        clear_graph_feature_cache()
         store, hub, svc0 = build_serving(seed=3)
         clock = ManualClock()
         obs = Observability(clock=clock, seed=13)
