@@ -14,9 +14,12 @@ with one root index per tree, so batched traversal advances every
 (row × tree) slot together with vectorized gathers.  Leaf self-loops
 make each step idempotent — a slot that reached its leaf stays there —
 so ``max_depth`` fixed passes replace per-slot active bookkeeping (the
-implicit mask; measured faster than explicit index compression) and the
-same property drives the fixed-depth `jax.jit` backend
-(`repro.kernels.tree_gather`).
+implicit mask; measured faster than explicit index compression).  The
+same property drives the `jax.jit` backend (`repro.kernels.tree_gather`):
+every tree acts as a complete tree of depth ``max_depth``, which a
+shallow bank (``max_depth <= tree_gather.DENSE_MAX_DEPTH``) traverses
+densely, by level-wise compare-and-select with no per-slot gather, and
+a deeper one by a fixed-depth loop of per-slot gathers.
 
 The traversal's hot layout is precomputed once per ensemble: `intp`
 indices (numpy fancy indexing converts anything else per call) and an
@@ -153,8 +156,8 @@ class FlatEnsemble:
         """Leaf value of every tree for every row → (n_rows, n_trees).
 
         ``backend``: "numpy" (default, bit-exact float64), "jax" (jit'd
-        gather loop on the resident bank), or "auto" (tiered by
-        `resolve_backend`).
+        traversal on the resident bank, dense or loop by depth), or
+        "auto" (tiered by `resolve_backend`).
         """
         x = np.ascontiguousarray(x, dtype=np.float64)
         if x.ndim != 2:

@@ -1,13 +1,32 @@
-"""Device-resident tree-ensemble gather backends (`jax.jit` + mesh sharding).
+"""Device-resident tree-ensemble traversal (`jax.jit` + mesh sharding).
 
-Batched tree traversal is a pure gather workload: every (row × tree)
-slot holds a node id, and one step gathers (feature, threshold, child)
-for all slots at once.  Because leaves self-loop (`left == right ==
-self` in `FlatEnsemble`), the update is idempotent, so a fixed-depth
-`lax.fori_loop` of ``max_depth`` iterations needs no active mask — rows
-that reached a leaf simply stay put.  That keeps the whole traversal one
-XLA computation (no host sync per level), which wins once
-rows × trees is large; the numpy mask loop wins on small batches.
+Every (row × tree) slot is routed from its tree's root to a leaf by the
+float32 test ``x[feature] <= threshold`` (left) at each node.  Leaves
+self-loop (`left == right == self` in `FlatEnsemble`), so a tree of any
+shape behaves as a complete tree of the bank's static depth ``D``: a
+leaf above depth ``D`` fills its subtree with itself.  Two forms of the
+same traversal compute bit-identical leaves, chosen by ``D``:
+
+* dense (``D <= DENSE_MAX_DEPTH``): the completed tree's node ids are
+  built level by level from the roots, ``(trees, 2**l)`` at level ``l``,
+  by gathers over the small bank arrays that do not depend on the rows.
+  Every node's decision for every row is then computed at once, its
+  feature value picked from the row's features by compare-and-select,
+  and the rows walk the levels by index arithmetic:
+  ``i_(l+1) = 2·i_l + bit_l``, with ``bit_l`` selected from the level's
+  ``2**l`` decisions by compare-and-select against ``i_l``; the leaf
+  value is a select over the ``2**D`` completed leaves.  No gather
+  touches the (rows × trees) slot array, which is the access pattern a
+  TPU serves worst, and there is no loop.
+* loop (deeper banks): a fixed-depth `lax.fori_loop` that gathers
+  (feature, threshold, child) for all slots at once per level.  The
+  dense form's work and intermediates grow as ``2**D`` a slot, the
+  loop's as ``D``, so deep banks (a random forest's default depth 14)
+  keep it.
+
+Either way the whole traversal is one XLA computation (no host sync per
+level), which wins once rows × trees is large; the numpy mask loop wins
+on small batches.
 
 Residency (`DeviceBank`): the flattened struct-of-arrays bank is
 uploaded to the accelerator ONCE per `FlatEnsemble` and reused across
@@ -50,14 +69,25 @@ from repro.obs.tracing import Tracer
 # reports both views: what is resident now and what was ever uploaded.
 # ``programs_traced`` counts traversal programs traced for a new shape
 # or mesh, each of which is then compiled or fetched from the
-# persistent cache.
+# persistent cache; ``dense_programs_traced`` those of them that took
+# the dense form.
 _COUNTERS = {"banks_built": 0, "bank_bytes": 0, "inputs_staged": 0,
-             "input_bytes": 0, "programs_traced": 0}
+             "input_bytes": 0, "programs_traced": 0,
+             "dense_programs_traced": 0}
 _COUNTERS_LOCK = threading.Lock()
 
 # Flushes below this many rows skip mesh sharding: the all-gather +
 # dispatch overhead beats the per-device win on small batches.
 SHARD_MIN_ROWS = 1024
+
+# Banks up to this static depth traverse in the dense form, deeper ones
+# in the gather loop (module docstring).  The dense form's selects,
+# intermediates and compile time grow as 2**depth.  On a TPU v5e it
+# ran 40-500x faster than the loop at depths 4 to 8 (150 trees, 2,048
+# and 8,192 rows) and compiled in about the loop's time through depth
+# 6; at depth 8 it compiled several times slower, which a program
+# compiled per row count pays (PERF.md, section 6).
+DENSE_MAX_DEPTH = 6
 
 
 def residency_counters() -> Dict[str, int]:
@@ -72,7 +102,13 @@ def _count(**deltas: int) -> None:
             _COUNTERS[k] += v
 
 
-def _traverse_core(feature, threshold, left, right, value, roots, x, *,
+def traversal_form(depth: int) -> str:
+    """``"dense"`` or ``"loop"``: the form a bank of static ``depth``
+    traverses in."""
+    return "dense" if depth <= DENSE_MAX_DEPTH else "loop"
+
+
+def _traverse_loop(feature, threshold, left, right, value, roots, x, *,
                    depth):
     # Level 0 is peeled out of the loop: every row starts at the same
     # roots, so it is a plain column gather, and the loop carry it
@@ -91,6 +127,56 @@ def _traverse_core(feature, threshold, left, right, value, roots, x, *,
     return value[nid]
 
 
+def _select(idx, columns):
+    """``columns[idx]`` elementwise by compare-and-select: ``columns`` is
+    a static list of arrays that broadcast against ``idx``, and every
+    ``idx`` lies in ``range(len(columns))``.  Selecting copies bits, so
+    the result is exact in any dtype."""
+    out = columns[0]
+    for k in range(1, len(columns)):
+        out = jnp.where(idx == k, columns[k], out)
+    return out
+
+
+def _traverse_dense(feature, threshold, left, right, value, roots, x, *,
+                    depth):
+    # The completed tree's node ids, level by level: (trees, 2**l) at
+    # level l, children interleaved, so position j's children sit at 2j
+    # and 2j + 1 of the next level.
+    n_trees = roots.shape[0]
+    levels = [roots[:, None]]
+    for _ in range(depth):
+        ids = levels[-1]
+        levels.append(jnp.stack([left[ids], right[ids]], axis=-1)
+                      .reshape(n_trees, -1))
+    inner = jnp.concatenate(levels[:-1], axis=1)     # (trees, 2**depth - 1)
+    # Every node's decision for every row, laid out (trees, nodes, rows),
+    # rows minor; a node's feature value is picked from the row's
+    # features by compare-and-select (exact, unlike a matmul at the
+    # TPU's default precision), not by a column gather.
+    xv = _select(feature[inner][..., None],
+                 [x[:, f][None, None, :] for f in range(x.shape[1])])
+    goes_right = ~(xv <= threshold[inner][..., None])
+    # Walk the levels: ``at`` is the position each slot stands on.
+    at = 0
+    for level in range(depth):
+        first = 2 ** level - 1
+        bit = _select(at, [goes_right[:, first + j]
+                           for j in range(2 ** level)])
+        at = 2 * at + bit.astype(jnp.int32)                  # (trees, rows)
+    leaves = value[levels[-1]]                         # (trees, 2**depth)
+    return _select(at, [leaves[:, j:j + 1]
+                        for j in range(leaves.shape[1])]).T
+
+
+def _traverse_core(feature, threshold, left, right, value, roots, x, *,
+                   depth):
+    form = (_traverse_dense if traversal_form(depth) == "dense"
+            else _traverse_loop)
+    return form(feature, threshold, left, right, value, roots, x,
+                depth=depth)
+
+
 def _fused_core(feature, raw_threshold, left, right, value, roots,
                 scale, bias, x, *, depth, kind):
     # Raw features against raw-space thresholds (`raw_thresholds`): the
@@ -103,15 +189,17 @@ def _fused_core(feature, raw_threshold, left, right, value, roots,
 
 def _entry(core):
     """``core`` as a jitted program's entry point: counts one traced
-    program each time JAX traces it.  The count is a trace-time side
-    effect, so a cached program's call costs nothing; it sits on the
-    entry and not in the shared bodies (`_fused_core` calls
-    `_traverse_core`).  `wraps` keeps the XLA module name
-    ``jit_<core name>`` that the device-trace readers match."""
+    program, and whether it took the dense form, each time JAX traces
+    it.  The count is a trace-time side effect, so a cached program's
+    call costs nothing; it sits on the entry and not in the shared
+    bodies (`_fused_core` calls `_traverse_core`).  `wraps` keeps the
+    XLA module name ``jit_<core name>`` that the device-trace readers
+    match."""
     @wraps(core)
-    def traced(*args, **kwargs):
-        _count(programs_traced=1)
-        return core(*args, **kwargs)
+    def traced(*args, depth, **kwargs):
+        _count(programs_traced=1,
+               dense_programs_traced=int(traversal_form(depth) == "dense"))
+        return core(*args, depth=depth, **kwargs)
     return traced
 
 
@@ -154,8 +242,9 @@ class DeviceBank:
         db.n_trees = flat.n_trees
         db.depth = max(1, flat.max_depth)
         db.mesh = flush_mesh()
-        # Leaves carry feature = -1; clamp to 0 so the take_along_axis
-        # gather stays in-bounds (self-looped slots ignore the compare).
+        # Leaves carry feature = -1; clamp to 0 so every node names a
+        # real column in either form (a leaf's children are itself, so
+        # its compare is ignored).
         host = (np.maximum(flat.feature, 0).astype(np.int32),
                 f32_thresholds(flat.threshold, 0.0, 1.0),
                 flat.left.astype(np.int32),
@@ -264,7 +353,7 @@ def _row_sharded(xd) -> bool:
 # -- public backends ----------------------------------------------------------
 
 def predict_trees_jax(flat, x: np.ndarray) -> np.ndarray:
-    """(n_rows, n_trees) leaf values via the jit'd gather loop.
+    """(n_rows, n_trees) leaf values via the jit'd traversal.
 
     Bank arrays come from the persistent `DeviceBank` (uploaded once per
     ensemble); only the f32 input is transferred per flush.
@@ -335,8 +424,8 @@ def fused_predict(flat, raw_threshold, reduction: Tuple, x: np.ndarray,
     With an enabled ``tracer`` the call records three spans, children
     of the caller's ambient span: ``tree.stage`` (the float32 batch and
     its transfer; attrs ``rows``, ``bytes``), ``tree.dispatch`` (the
-    program and the slice are enqueued) and ``tree.wait`` (the host
-    blocks on the readback).
+    program and the slice are enqueued; attr ``form``, ``"dense"`` or
+    ``"loop"``) and ``tree.wait`` (the host blocks on the readback).
     """
     kind, scale, bias = reduction
     tracer = _UNTRACED if tracer is None else tracer
@@ -347,8 +436,10 @@ def fused_predict(flat, raw_threshold, reduction: Tuple, x: np.ndarray,
         if tracer.enabled:
             sp.set_attr("rows", n)
             sp.set_attr("bytes", int(xd.nbytes))
-    with tracer.span("tree.dispatch"):
+    with tracer.span("tree.dispatch") as sp:
         out = db.fused(raw_threshold, jnp.float32(scale), jnp.float32(bias),
                        xd, kind)[:n]
+        if tracer.enabled:
+            sp.set_attr("form", traversal_form(db.depth))
     with tracer.span("tree.wait"):
         return np.asarray(out, dtype=np.float64)

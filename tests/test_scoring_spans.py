@@ -110,7 +110,8 @@ def test_fused_predict_batch_span_tree(hub):
         rows = k["attrs"]["rows"]
         assert stage["attrs"] == {"rows": rows,
                                   "bytes": 4 * rows * len(model.scaler.mean)}
-        assert tree[1]["attrs"] == tree[2]["attrs"] == {}
+        assert tree[1]["attrs"] == {"form": "dense"}   # depth <= 4 banks
+        assert tree[2]["attrs"] == {}
     # The report cache cleared, the same graphs featurize from the
     # feature cache: nothing is computed again.
     svc.clear_cache()

@@ -5,9 +5,12 @@ topology that is described, not attached, so what the chip's compiler
 would refuse fails here first, on a host without a TPU.
 
 Cases: the fused tree traversal that serves flushes, at the chip
-smoke's bank size and at a larger one; every distinct op kind of
-MobileNetV2 (width 1.0, 224×224) as the profiler builds it; and the
-row-sharded fused flush on a four-device mesh.
+smoke's bank size, at a larger one, and at a random forest's default
+depth, each pinned to its form (the dense form has no ``while``, the
+loop form has one) and to the module name ``jit__fused_core`` that the
+benchmark's trace readers match; every distinct op kind of MobileNetV2
+(width 1.0, 224×224) as the profiler builds it; and the row-sharded
+fused flush on a four-device mesh.
 
 The topology is described inside a module-scoped fixture, never while
 a module is imported, and all cases stay in this one file: only one
@@ -25,12 +28,17 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from repro.core.executor import build_op_fn
 from repro.core.realworld import mobilenet_v2
-from repro.kernels.tree_gather import DeviceBank, _fused, _fused_core
+from repro.kernels.tree_gather import (
+    DeviceBank, _fused, _fused_core, traversal_form,
+)
 
 # GBDT at the chip smoke's hyperparameters (150 stages, depth 4) over
-# ~16 features, and a larger bank; (rows, trees, nodes, features, depth).
+# ~16 features, a larger bank, and a random forest at its default depth
+# 14; (rows, trees, nodes, features, depth).
 BANKS = {"smoke_bank": (8192, 150, 150 * 31, 16, 4),
-         "large_bank": (65536, 400, 400 * 63, 24, 6)}
+         "large_bank": (65536, 400, 400 * 63, 24, 6),
+         "deep_forest": (8192, 100, 100 * 2047, 16, 14)}
+FORMS = {"smoke_bank": "dense", "large_bank": "dense", "deep_forest": "loop"}
 
 MNV2 = mobilenet_v2(1.0, 224)
 
@@ -81,6 +89,10 @@ def test_fused_traversal_compiles(one_chip, bank):
     x = s((rows, features), jnp.float32)
     compiled = _fused.lower(*args, x, depth=depth, kind="sum").compile()
     assert compiled.out_info.shape == (rows,)
+    assert traversal_form(depth) == FORMS[bank]
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit__fused_core")
+    assert (" while(" in hlo) == (FORMS[bank] == "loop")
 
 
 @pytest.mark.parametrize("kind", sorted(MNV2_KINDS))
