@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``.
 
 Every report the timed path returned in the window is compared with
-the float64 reference of the same graph:
+the float64 reference of the same graph, featurized by the
+configuration's reference table (`chipbench.spec.reference_features`):
 
 * ``e2e_gap``: the widest relative gap of a report's end-to-end
   seconds from the reference's;
@@ -19,7 +20,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from chipbench.reference import ReferenceBank, predict_graphs
+from chipbench.reference import FeatureTable, ReferenceBank, predict_graphs
 
 NUMBERS = ("e2e_gap", "op_gap", "structure", "unanswered")
 
@@ -51,10 +52,11 @@ class Answers:
         return len(self.rows)
 
 
-def readings(bank: ReferenceBank, answers: Answers, unanswered: int = 0,
-             precision: str = "float64",
+def readings(bank: ReferenceBank, features: FeatureTable, answers: Answers,
+             unanswered: int = 0, precision: str = "float64",
              against: str = "program") -> Dict[str, float]:
-    """The numbers for ``answers``.
+    """The numbers for ``answers``, their op features computed by
+    ``features``.
 
     ``against="program"`` compares the program's reports; ``"control"``
     puts the reference computed in ``precision`` in the program's place
@@ -62,8 +64,8 @@ def readings(bank: ReferenceBank, answers: Answers, unanswered: int = 0,
     names = list(answers.graphs)
     graphs = [g if isinstance(g, dict) else g.to_json()
               for g in (answers.graphs[n] for n in names)]
-    ref = dict(zip(names, predict_graphs(bank, graphs)))
-    ctl = dict(zip(names, predict_graphs(bank, graphs, precision))) \
+    ref = dict(zip(names, predict_graphs(bank, graphs, features)))
+    ctl = dict(zip(names, predict_graphs(bank, graphs, features, precision))) \
         if against == "control" else {}
     e2e_gap = op_gap = 0.0
     structure = 0

@@ -37,14 +37,16 @@ def readings(bench, cell, seeds, seconds, devices, peak):
     try:
         hub, _ = bank.train_hub(cfg, os.path.join(workdir, "hub"))
         ref = ReferenceBank.load(bank.bank_file(hub.root))
+        features = spec.reference_features(cfg)
         for seed in seeds:
             out = harness.execute(cfg, tspec, seed=seed, seconds=seconds,
                                   trace=False, t_start=time.perf_counter(),
                                   devices=devices, workdir=workdir, peak=peak,
                                   hub=hub)
             run = out["run"]
-            prog = compare.readings(ref, out["answers"], unanswered=run.failed)
-            ctl = compare.readings(ref, out["answers"],
+            prog = compare.readings(ref, features, out["answers"],
+                                    unanswered=run.failed)
+            ctl = compare.readings(ref, features, out["answers"],
                                    precision=CONTROL_PRECISION,
                                    against="control")
             yield seed, prog, ctl, run

@@ -1,16 +1,25 @@
 """One run of one cell: set-up, window, trace, comparison, result line.
 
 The harness is driven by data: the cell names a configuration file and
-a traffic file whose ``kind`` names the drive module
-(``chipbench/drive_<kind>.py``), and BENCHMARK.json lists the metrics,
-each computed by its own reader in ``metrics/``.  Every drive module has
-one entry, ``drive(cfg, spec, *, hub, obs, seed, seconds, on_window)``,
-which returns the run record and the window's answers.
+a traffic file, and BENCHMARK.json lists the metrics, each computed by
+its own reader in ``metrics/``.  Three modules plug in by name
+(`chipbench.spec`):
+
+* the traffic file's ``kind`` names the drive module
+  ``chipbench/drive_<kind>.py``, whose one entry,
+  ``drive(cfg, spec, *, hub, obs, seed, seconds, on_window)``, returns
+  the run record and the window's answers;
+* the configuration's ``graphs`` (``nas`` where it has none) names its
+  graph source ``chipbench/graphs_<name>.py``: the graphs the bank is
+  profiled on, and the generator that the traffic digest guards;
+* the same name selects its reference table
+  ``chipbench/reference_<name>.py``: the op features the comparison
+  computes.  Set-up stops, before the window, on an op type of those
+  graphs that the table lacks.
 """
 from __future__ import annotations
 
 import gc
-import importlib
 import json
 import os
 import shutil
@@ -46,13 +55,7 @@ def _bank_shapes(hub: Any) -> Dict[str, Dict[str, int]]:
 
 def drive_module(kind: str) -> Any:
     """``chipbench.drive_<kind>``, the module that drives a traffic kind."""
-    try:
-        return importlib.import_module(f"chipbench.drive_{kind}")
-    except ModuleNotFoundError as e:
-        if e.name != f"chipbench.drive_{kind}":
-            raise
-        raise ValueError(f"unknown traffic kind {kind!r}: no "
-                         f"chipbench/drive_{kind}.py") from None
+    return spec.plugin("drive", kind, "traffic kind")
 
 
 def execute(cfg: Dict[str, Any], tspec: Dict[str, Any], *, seed: int,
@@ -147,7 +150,8 @@ def run_and_print(bench: Dict[str, Any], cell: Dict[str, Any], *, seed: int,
         run = out["run"]
         run.cell = cell["name"]
         numbers = compare.readings(
-            ReferenceBank.load(out["bank_file"]), out["answers"],
+            ReferenceBank.load(out["bank_file"]),
+            spec.reference_features(cfg), out["answers"],
             unanswered=run.failed)
     finally:
         shutil.rmtree(workdir, ignore_errors=True)

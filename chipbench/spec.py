@@ -1,7 +1,23 @@
 """Finds a cell's pieces by name: BENCHMARK.json, configuration and
-traffic files, peaks, and the reader of each metric."""
+traffic files, peaks, the reader of each metric, and the modules a
+cell's data names.
+
+Three kinds of module plug in by name, each a file of its own under
+``chipbench/``, so that a new cell or configuration adds files and
+edits none:
+
+* ``drive_<kind>.py``: drives a traffic file's ``kind``;
+* ``graphs_<name>.py``: the graphs of a configuration whose file says
+  ``"graphs": "<name>"`` (``nas`` where it says nothing), with
+  ``sample_graphs(cfg, rng, n)`` (the generator) and
+  ``training_graphs(cfg)`` (what the bank is profiled on);
+* ``reference_<name>.py``: the same configuration's reference op
+  features, ``FEATURES`` (op type to feature list, read from a graph's
+  JSON wire form), for the op types its graphs bring.
+"""
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
@@ -74,6 +90,34 @@ def cell_metrics(bench: Dict[str, Any], cell_name: str,
     return [m for m in bench["per_layer"]
             if cell_name in m.get("workloads", [cell_name])
             and m["moves"] in names]
+
+
+def plugin(prefix: str, name: str, what: str) -> Any:
+    """``chipbench.<prefix>_<name>``; ``what`` says who named it."""
+    module = f"chipbench.{prefix}_{name}"
+    try:
+        return importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise ValueError(f"unknown {what} {name!r}: no "
+                         f"chipbench/{prefix}_{name}.py") from None
+
+
+def graphs_name(cfg: Dict[str, Any]) -> str:
+    return cfg.get("graphs", "nas")
+
+
+def graph_source(cfg: Dict[str, Any]) -> Any:
+    """The module of ``cfg``'s graphs (``graphs_<name>.py``)."""
+    return plugin("graphs", graphs_name(cfg),
+                  f"graph source of configuration {cfg['name']}")
+
+
+def reference_features(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """``FEATURES`` of ``cfg``'s reference (``reference_<name>.py``)."""
+    return plugin("reference", graphs_name(cfg),
+                  f"reference of configuration {cfg['name']}").FEATURES
 
 
 def reader(metric_name: str) -> Callable[[Any], Any]:

@@ -34,7 +34,8 @@ def setup(tmp_path_factory):
     search = dict(spec.traffic("search_p512"), population=128, children=128,
                   cycle_generations=2)
     return {"cfg": cfg, "hub": hub, "ref": ReferenceBank.load(
-        bank.bank_file(root)), "search": search,
+        bank.bank_file(root)), "features": spec.reference_features(cfg),
+        "search": search,
         "workdir": str(tmp_path_factory.mktemp("work"))}
 
 
@@ -46,8 +47,8 @@ def _run(setup, seconds):
                           devices=jax.devices(), workdir=setup["workdir"],
                           peak=PEAK, hub=setup["hub"])
     run = out["run"]
-    numbers = compare.readings(setup["ref"], out["answers"],
-                               unanswered=run.failed)
+    numbers = compare.readings(setup["ref"], setup["features"],
+                               out["answers"], unanswered=run.failed)
     return out, numbers, compare.judge(numbers, setup["cfg"]["limits"])
 
 
@@ -91,8 +92,9 @@ def test_search_run_is_correct_and_its_control_is_not(setup):
     out, numbers, ok = _run(setup, 0.5)
     assert ok, numbers
     assert out["run"].cands > 0 and numbers["e2e_gap"] < 1e-5
-    control = compare.readings(setup["ref"], out["answers"],
-                               precision="bfloat16", against="control")
+    control = compare.readings(setup["ref"], setup["features"],
+                               out["answers"], precision="bfloat16",
+                               against="control")
     assert not compare.judge(control, setup["cfg"]["limits"]), control
 
 
@@ -143,7 +145,7 @@ _CROSS_CHIP = textwrap.dedent("""
                           trace=False, t_start=0.0, devices=jax.devices(),
                           workdir=root, peak={peak!r}, hub=hub)
     numbers = compare.readings(ReferenceBank.load(bank.bank_file(hub.root)),
-                               out["answers"])
+                               spec.reference_features(cfg), out["answers"])
     sharded = hub.banks and sum(
         m.flat()._device_bank is not None and m.flat()._device_bank.mesh
         is not None for b in hub.banks.values() for m in b.predictors.values())

@@ -15,6 +15,7 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 from chipbench import harness, roofline, spec  # noqa: E402
 from chipbench.reference import ReferenceBank, predict_graphs  # noqa: E402
+from chipbench.reference_nas import FEATURES  # noqa: E402
 
 
 # -- traffic kinds ----------------------------------------------------------
@@ -97,7 +98,7 @@ def _graph(c, kind):
 ])
 def test_reference_walks_a_hand_built_bank(c, kind, stump, deep):
     bank = ReferenceBank(_two_tree_bank())
-    (rep,) = predict_graphs(bank, [_graph(c, kind)])
+    (rep,) = predict_graphs(bank, [_graph(c, kind)], FEATURES)
     op = 1e-3 + 0.5 * stump + 0.5 * deep
     assert rep["per_op"] == [("elementwise", pytest.approx(op)),
                              ("pad", 0.0)]        # no pad model: 0
@@ -107,8 +108,9 @@ def test_reference_walks_a_hand_built_bank(c, kind, stump, deep):
 
 def test_bfloat16_control_rounds_leaves():
     bank = ReferenceBank(_two_tree_bank())
-    (f64,) = predict_graphs(bank, [_graph(8, "neg")])
-    (bf,) = predict_graphs(bank, [_graph(8, "neg")], precision="bfloat16")
+    (f64,) = predict_graphs(bank, [_graph(8, "neg")], FEATURES)
+    (bf,) = predict_graphs(bank, [_graph(8, "neg")], FEATURES,
+                          precision="bfloat16")
     assert bf["e2e_s"] != f64["e2e_s"]
     assert bf["e2e_s"] == pytest.approx(f64["e2e_s"], rel=1e-2)
 
