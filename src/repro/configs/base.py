@@ -36,6 +36,16 @@ class ArchConfig:
     num_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    n_shared_experts: int = 0      # experts every token passes through
+    moe_d_ff: int = 0              # routed/shared expert width; 0 → d_ff
+    first_k_dense: int = 0         # leading layers with a dense FFN of d_ff
+
+    # latent attention (MLA, DeepSeek-V2 §2.1); kv_lora_rank 0 → none
+    kv_lora_rank: int = 0          # width of the compressed KV latent
+    q_lora_rank: int = 0           # 0 → queries projected directly
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0      # the decoupled rope key, shared by heads
+    v_head_dim: int = 0
 
     # SSM (Mamba2/SSD)
     ssm_state: int = 0
@@ -87,44 +97,67 @@ class ArchConfig:
     def has_decoder(self) -> bool:
         return True  # all assigned archs have an autoregressive decoder
 
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    def _attn_params(self) -> int:
+        d, H, h = self.d_model, self.num_heads, self.head_dim
+        if not self.kv_lora_rank:
+            return d * (H * h) + 2 * d * (self.num_kv_heads * h) + (H * h) * d
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        q = (d * self.q_lora_rank + self.q_lora_rank * H * qk
+             if self.q_lora_rank else d * H * qk)
+        kv = (d * (self.kv_lora_rank + self.qk_rope_head_dim)
+              + self.kv_lora_rank * H * (self.qk_nope_head_dim
+                                         + self.v_head_dim))
+        return q + kv + H * self.v_head_dim * d
+
+    def _moe_layers_params(self, experts: int) -> int:
+        """Leading dense layers plus the MoE layers, each MoE FFN
+        counting ``experts`` routed experts, the shared ones and the
+        router (norms are left out, as everywhere here)."""
+        d, attn = self.d_model, self._attn_params()
+        dense = attn + 3 * d * self.d_ff
+        moe = (attn + 3 * d * self.expert_d_ff
+               * (experts + self.n_shared_experts) + d * self.num_experts)
+        return (self.first_k_dense * dense
+                + (self.num_layers - self.first_k_dense) * moe)
+
     def num_params(self) -> int:
         """Approximate parameter count N (used for MODEL_FLOPS = 6·N·D)."""
         d, L = self.d_model, self.num_layers
-        h = self.head_dim
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+        if self.family == "moe":
+            return int(emb + self._moe_layers_params(self.num_experts))
         per_layer = 0
         if self.family in ("dense", "vlm", "encdec"):
-            qkv = d * (self.num_heads * h) + 2 * d * (self.num_kv_heads * h) + (self.num_heads * h) * d
             n_mats = 2 if self.mlp_kind == "gelu" else 3
-            mlp = n_mats * d * self.d_ff
-            per_layer = qkv + mlp
-        elif self.family == "moe":
-            qkv = d * (self.num_heads * h) + 2 * d * (self.num_kv_heads * h) + (self.num_heads * h) * d
-            mlp = 3 * d * self.d_ff * self.num_experts + d * self.num_experts
-            per_layer = qkv + mlp
+            per_layer = self._attn_params() + n_mats * d * self.d_ff
         elif self.family in ("ssm", "hybrid"):
             d_in = d * self.ssm_expand
             per_layer = d * (2 * d_in + 2 * self.ssm_state) + d_in * d
             if self.family == "hybrid":
-                qkv = d * (self.num_heads * h) + 2 * d * (self.num_kv_heads * h) + (self.num_heads * h) * d
-                per_layer += (qkv + 3 * d * self.d_ff) // max(1, self.shared_attn_every)
+                per_layer += ((self._attn_params() + 3 * d * self.d_ff)
+                              // max(1, self.shared_attn_every))
         total = emb + L * per_layer
         if self.family == "encdec":
             total += self.encoder_layers * per_layer  # encoder stack
         if self.family == "vlm" and self.cross_attn_every:
             n_cross = L // self.cross_attn_every
-            total += n_cross * (2 * d * (self.num_kv_heads * h))
+            total += n_cross * (2 * d * (self.num_kv_heads * self.head_dim))
         return int(total)
 
     def active_params(self) -> int:
-        """N_active for MoE (6·N_active·D)."""
+        """N_active for MoE (6·N_active·D): the embedding and the head,
+        and per token the top-k routed experts, the shared experts and
+        the router of every MoE layer, beside attention and the leading
+        dense layers."""
         if self.family != "moe":
             return self.num_params()
-        d, L, h = self.d_model, self.num_layers, self.head_dim
-        emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        qkv = d * (self.num_heads * h) + 2 * d * (self.num_kv_heads * h) + (self.num_heads * h) * d
-        mlp = 3 * d * self.d_ff * self.top_k + d * self.num_experts
-        return int(emb + L * (qkv + mlp))
+        emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings
+                                                else 2)
+        return int(emb + self._moe_layers_params(self.top_k))
 
     def reduced(self) -> "ArchConfig":
         """Smoke-test variant: same family/structure, tiny sizes."""
@@ -146,6 +179,14 @@ class ArchConfig:
             cross_attn_every=2 if self.cross_attn_every else 0,
             vision_seq=16 if self.vision_seq else 0,
             shared_attn_every=2 if self.shared_attn_every else 0,
+            n_shared_experts=min(self.n_shared_experts, 2),
+            moe_d_ff=32 if self.moe_d_ff else 0,
+            first_k_dense=min(self.first_k_dense, 1),
+            kv_lora_rank=32 if self.kv_lora_rank else 0,
+            q_lora_rank=48 if self.q_lora_rank else 0,
+            qk_nope_head_dim=16 if self.kv_lora_rank else 0,
+            qk_rope_head_dim=8 if self.kv_lora_rank else 0,
+            v_head_dim=16 if self.kv_lora_rank else 0,
             q_chunk=64,
             name=self.name + "-reduced",
         )
@@ -178,6 +219,9 @@ INPUT_SHAPES: Dict[str, InputShape] = {
 
 def shape_applicable(cfg: ArchConfig, shape: InputShape) -> Tuple[bool, str]:
     """Whether an (arch × shape) cell runs, and if not, why (DESIGN.md §4)."""
+    if cfg.kv_lora_rank:
+        return False, ("latent attention: latency prediction only "
+                       "(repro.core.lm_lowering); `build_model` has no MLA layer")
     if shape.name == "long_500k" and not cfg.is_subquadratic:
         return False, ("long_500k requires a sub-quadratic context path; "
                        f"{cfg.name} is a full-attention architecture (skip per assignment)")

@@ -13,6 +13,7 @@ from repro.configs.qwen2_72b import CONFIG as QWEN2
 from repro.configs.gemma2_27b import CONFIG as GEMMA2
 from repro.configs.starcoder2_15b import CONFIG as STARCODER2
 from repro.configs.deepseek_67b import CONFIG as DEEPSEEK
+from repro.configs.deepseek_v2_lite import CONFIG as DEEPSEEK_V2_LITE
 from repro.configs.llama32_vision_90b import CONFIG as LLAMA_VISION
 from repro.configs.mamba2_2p7b import CONFIG as MAMBA2
 from repro.configs.qwen3_moe_235b import CONFIG as QWEN3_MOE
@@ -22,7 +23,7 @@ from repro.configs.zamba2_1p2b import CONFIG as ZAMBA2
 ARCHS: Dict[str, ArchConfig] = {
     c.name: c
     for c in (WHISPER, QWEN2, GEMMA2, STARCODER2, DEEPSEEK, LLAMA_VISION,
-              MAMBA2, QWEN3_MOE, GRANITE_MOE, ZAMBA2)
+              MAMBA2, QWEN3_MOE, GRANITE_MOE, ZAMBA2, DEEPSEEK_V2_LITE)
 }
 
 

@@ -478,6 +478,108 @@ def _f_collective(graph, node):
 
 
 # ---------------------------------------------------------------------------
+# Latent attention and routed experts (DeepSeek-V2, arXiv 2405.04434 §2.1-2.2)
+#
+# FLOPs count a multiply-accumulate as 2 and, per attention score, the
+# scale, max, subtract, sum and divide of the softmax (the exponential is
+# a transcendental, not counted).  Bytes are those the op must move at
+# ``elem_bytes`` an element (2: bfloat16).
+# ---------------------------------------------------------------------------
+
+SCORE_FLOPS = 5      # scale + softmax's max, subtract, sum, divide
+MASK_FLOPS = 1       # the prefill form's causal mask, a select a score
+SWIGLU_FLOPS = 5     # silu(g) * u an element: negate, add, divide, 2 multiplies
+
+
+@register_featurizer("mla_prefill")
+def _f_mla_prefill(graph, node):
+    """Prefill form: the chunk's queries attend over K/V decompressed
+    from the latent cache of prefix + chunk (``kv_len`` keys), with
+    per-head qk dim nope + rope and v dim ``v_head_dim``."""
+    chunk = node.param("chunk", 1)
+    prefix = node.param("prefix", 0)
+    heads = node.param("heads", 1)
+    qk = node.param("qk_head_dim", 1)
+    rope = node.param("rope_dim", 0)
+    v = node.param("v_head_dim", 1)
+    eb = node.param("elem_bytes", 2)
+    kv_len = prefix + chunk
+    scores = heads * chunk * kv_len
+    flops = 2.0 * scores * (qk + v) + (SCORE_FLOPS + MASK_FLOPS) * scores
+    kv_bytes = float(eb * kv_len * (heads * (qk - rope + v) + rope))
+    names = ["chunk", "prefix", "kv_len", "heads", "qk_head_dim",
+             "v_head_dim", "kv_bytes", "flops"]
+    _cache_names("mla_prefill", names)
+    return names, [chunk, prefix, kv_len, heads, qk, v, kv_bytes, flops]
+
+
+@register_featurizer("mla_decode")
+def _f_mla_decode(graph, node):
+    """Absorbed decode form: each sequence's one query, already through
+    ``W_UK``, attends over its latent cache (``latent_dim + rope_dim``
+    wide) and returns a latent-space output for ``W_UV``; ``kv_total``
+    keys over all sequences, the longest ``kv_max``."""
+    seqs = node.param("seqs", 1)
+    kv_total = node.param("kv_total", 1)
+    kv_max = node.param("kv_max", 1)
+    heads = node.param("heads", 1)
+    latent = node.param("latent_dim", 1)
+    rope = node.param("rope_dim", 0)
+    eb = node.param("elem_bytes", 2)
+    scores = heads * kv_total
+    flops = 2.0 * scores * (2 * latent + rope) + SCORE_FLOPS * scores
+    cache_bytes = float(eb * kv_total * (latent + rope))
+    # The cache bytes lead: a decode step is bound by reading the cache,
+    # and among equal splits a tree takes the first feature.
+    names = ["seqs", "cache_bytes", "kv_total", "kv_max", "heads",
+             "latent_dim", "rope_dim", "flops"]
+    _cache_names("mla_decode", names)
+    return names, [seqs, cache_bytes, kv_total, kv_max, heads, latent,
+                   rope, flops]
+
+
+@register_featurizer("moe_router")
+def _f_moe_router(graph, node):
+    """Router logits over every routed expert, softmax scoring, top-k."""
+    tokens = node.param("tokens", 1)
+    d_model = node.param("d_model", 1)
+    experts = node.param("experts", 1)
+    top_k = node.param("top_k", 1)
+    eb = node.param("elem_bytes", 2)
+    # the logits, then the softmax's max, subtract, sum and divide
+    flops = 2.0 * tokens * d_model * experts + 4.0 * tokens * experts
+    weight_bytes = float(eb * d_model * experts)
+    names = ["tokens", "d_model", "experts", "top_k", "weight_bytes",
+             "flops"]
+    _cache_names("moe_router", names)
+    return names, [tokens, d_model, experts, top_k, weight_bytes, flops]
+
+
+@register_featurizer("moe_routed")
+def _f_moe_routed(graph, node):
+    """The routed experts held here (SwiGLU of width ``d_ff``) over the
+    tokens routed to them: ``routed`` in all, ``max_load`` at the
+    busiest expert, ``active`` experts with any token (their weights
+    are read); each output is weighted by its gate and summed back."""
+    held = node.param("experts_held", 1)
+    active = node.param("active", 1)
+    routed = node.param("routed", 0)
+    max_load = node.param("max_load", 0)
+    d_model = node.param("d_model", 1)
+    d_ff = node.param("d_ff", 1)
+    eb = node.param("elem_bytes", 2)
+    flops = (6.0 * routed * d_model * d_ff + SWIGLU_FLOPS * routed * d_ff
+             + 2.0 * routed * d_model)
+    weight_bytes = float(eb * active * 3 * d_model * d_ff)
+    act_bytes = float(eb * 2 * routed * d_model)
+    names = ["experts_held", "active", "routed", "max_load", "d_model",
+             "d_ff", "weight_bytes", "act_bytes", "flops"]
+    _cache_names("moe_routed", names)
+    return names, [held, active, routed, max_load, d_model, d_ff,
+                   weight_bytes, act_bytes, flops]
+
+
+# ---------------------------------------------------------------------------
 # Whole-graph feature matrices (the prediction fast path's feature cache)
 # ---------------------------------------------------------------------------
 

@@ -8,7 +8,8 @@ that computational graph: nodes are operations, edges are tensors.
 Two frontends produce `OpGraph`s:
   * `repro.core.nas_space` / `repro.core.realworld` — conv-net builders
     (the paper's NAS space and real-world architectures);
-  * `repro.core.graph_capture` — jaxpr tracing of LM-family models.
+  * `repro.core.lm_lowering` — one chip's step of an LM-family
+    `ArchConfig` (prefill chunk and decodes, expert share) as op graphs.
 
 Two backends consume them:
   * `repro.core.executor` — turns graphs into jitted JAX callables for
@@ -22,6 +23,7 @@ import json
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
+import ml_dtypes  # noqa: F401  (registers "bfloat16" as a numpy dtype name)
 import numpy as np
 
 # ---------------------------------------------------------------------------
@@ -70,6 +72,12 @@ OP_TYPES = CONV_OPS + (
     "ssd_scan",          # Mamba2 state-space scan
     "elementwise_lm",    # fused vector ops in LM graphs
     "collective",        # all_reduce / all_gather / ... (distributed graphs)
+    "mla_prefill",       # latent attention, prefill form: K/V decompressed
+                         # from the latent cache over prefix + chunk
+    "mla_decode",        # latent attention, absorbed decode form over the
+                         # latent cache (total and longest cache length)
+    "moe_router",        # router logits, softmax scoring and top-k
+    "moe_routed",        # the routed experts held here, at the step's load
 )
 
 

@@ -67,6 +67,12 @@ def _token_specs(shape: InputShape, cfg: ArchConfig,
 
 
 def build_model(cfg: ArchConfig) -> Model:
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention has no trainable layer here; it "
+            f"is lowered to op graphs for latency prediction "
+            f"(repro.core.lm_lowering) against its plain reference "
+            f"(repro.models.deepseek_v2_ref)")
     if cfg.family in ("dense", "moe", "vlm"):
         return _build_decoder(cfg)
     if cfg.family == "ssm":

@@ -10,7 +10,9 @@ depth, each pinned to its form (the dense form has no ``while``, the
 loop form has one) and to the module name ``jit__fused_core`` that the
 benchmark's trace readers match; every distinct op kind of MobileNetV2
 (width 1.0, 224×224) as the profiler builds it; and the row-sharded
-fused flush on a four-device mesh.
+fused flush on a four-device mesh; and the plain DeepSeek-V2 reference
+layer at a toy size, whose FLOPs as the chip's compiler counts them
+bound the lowered step's from above.
 
 The topology is described inside a module-scoped fixture, never while
 a module is imported, and all cases stay in this one file: only one
@@ -26,11 +28,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
+from repro.configs import get_arch
 from repro.core.executor import build_op_fn
+from repro.core.features import featurize
+from repro.core.lm_lowering import ExpertShare, StepPlan, lower_step
 from repro.core.realworld import mobilenet_v2
 from repro.kernels.tree_gather import (
     DeviceBank, _fused, _fused_core, traversal_form,
 )
+from repro.models.deepseek_v2_ref import layer_flops
 
 # GBDT at the chip smoke's hyperparameters (150 stages, depth 4) over
 # ~16 features, a larger bank, and a random forest at its default depth
@@ -121,3 +127,39 @@ def test_sharded_fused_flush_compiles(topo):
     compiled = fn.lower(*args, x).compile()
     assert compiled.out_info.shape == (rows,)
     assert compiled.out_info.sharding.spec == jax.sharding.PartitionSpec("rows")
+
+
+TOY_LM = get_arch("deepseek-v2-lite").reduced()
+TOY_PLANS = {"decode": StepPlan(decodes=(5, 17, 33)),
+             "prefill": StepPlan(chunk=8, prefix=64),
+             "mixed": StepPlan(decodes=(5, 17), chunk=8, prefix=40,
+                               emits=True)}
+
+
+@pytest.mark.parametrize("kind", ["dense", "moe"])
+@pytest.mark.parametrize("plan", sorted(TOY_PLANS))
+def test_reference_layer_flops_on_the_chip_bound_the_lowering(one_chip,
+                                                              plan, kind):
+    # The chip's compiler counts more element-wise FLOPs than the
+    # lowering's equations (a softmax element 5, not 4; rope's angles
+    # and the residual adds): the lowering reads 1.1-3.2% below it at
+    # this size, 0.25-4.45% at the published widths.  A lowering that
+    # counts decode in the naive form, or leaves out the prefix's
+    # decompression, leaves these bounds on every plan with that form.
+    held = TOY_LM.num_experts // 4
+    share = ExpertShare(held=held, group=4, popularity=tuple(
+        tuple(TOY_LM.top_k / TOY_LM.num_experts for _ in range(held))
+        for _ in range(TOY_LM.num_layers - TOY_LM.first_k_dense)))
+    p = TOY_PLANS[plan]
+    dense = kind == "dense"
+    chip = layer_flops(TOY_LM, dense=dense, chunk=p.chunk, prefix=p.prefix,
+                       decodes=p.decodes,
+                       loads=() if dense else share.loads(p.tokens)[0],
+                       sharding=one_chip)
+    g = lower_step(TOY_LM, p, share)
+    lowered = 0.0
+    for n in g.nodes:
+        if n.param("layer") == (0 if dense else 1):
+            names, vals = featurize(g, n)
+            lowered += sum(v for k, v in zip(names, vals) if k == "flops")
+    assert 0 < (chip - lowered) / chip <= 0.05
