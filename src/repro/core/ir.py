@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import hashlib
 import json
+import threading
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -306,13 +307,139 @@ class OpGraph:
         g.output_ids = list(d["outputs"])
         return g
 
+    def canonical_json(self) -> str:
+        """``json.dumps(self.to_json(), sort_keys=True)``, byte for byte,
+        written without building the tree.
+
+        Keys are emitted in sorted order by construction, tensors in the
+        string order of their ids.  The text of each distinct ``params``
+        tuple and tensor (shape, dtype) comes from a bounded process-wide
+        memo (`fingerprint_memo_info`).  Only exactly typed entries are
+        memoized (params values ``int``, ``str`` or None; shape entries
+        ``int``), since ``1``, ``1.0`` and ``True`` are equal keys that
+        encode differently; the rest are encoded directly.
+        """
+        params_memo, tensor_memo = _PARAMS_TEXT, _TENSOR_TEXT
+        misses = 0
+        nodes = []
+        for n in self.nodes:
+            p = n.params
+            for _, v in p:
+                if type(v) is not int and type(v) is not str and v is not None:
+                    ptext = _JSON.encode([list(kv) for kv in p])
+                    misses += 1
+                    break
+            else:
+                ptext = params_memo.get(p)
+                if ptext is None:
+                    ptext = _plain_params_text(p)
+                    misses += 1
+            fused = _JSON.encode(list(n.fused)) if n.fused else "[]"
+            # A list of ints prints as its JSON text.
+            nodes.append(
+                f'{{"fused": {fused}, "inputs": {list(n.inputs)}, '
+                f'"op_id": {n.op_id}, "op_type": {_json_str(n.op_type)}, '
+                f'"outputs": {list(n.outputs)}, "params": {ptext}}}')
+        tensors = []
+        for key, info in sorted([(str(t), info)
+                                 for t, info in self.tensors.items()]):
+            shape, dtype = info.shape, info.dtype
+            for d in shape:
+                if type(d) is not int:
+                    ttext = _JSON.encode({"dtype": dtype, "shape": list(shape)})
+                    misses += 1
+                    break
+            else:
+                ttext = tensor_memo.get((shape, dtype))
+                if ttext is None:
+                    ttext = _tensor_text(shape, dtype)
+                    misses += 1
+            tensors.append(f'"{key}": {ttext}')
+        with _MEMO_LOCK:
+            _MEMO_COUNTS["lookups"] += len(nodes) + len(tensors)
+            _MEMO_COUNTS["misses"] += misses
+        return (f'{{"inputs": {list(self.input_ids)}, '
+                f'"name": {_json_str(self.name)}, "nodes": [{", ".join(nodes)}], '
+                f'"outputs": {list(self.output_ids)}, '
+                f'"tensors": {{{", ".join(tensors)}}}}}')
+
+    def _fp_guard(self) -> Tuple[int, int, int]:
+        return (len(self.nodes), len(self.tensors), len(self.output_ids))
+
+    def fingerprint_cached(self) -> bool:
+        """True when `fingerprint` would answer from the graph's memo."""
+        return self._fp is not None and self._fp[0] == self._fp_guard()
+
     def fingerprint(self) -> str:
-        """Content hash of the graph (cached — LRU lookups re-query it)."""
-        guard = (len(self.nodes), len(self.tensors), len(self.output_ids))
-        if self._fp is None or self._fp[0] != guard:
-            blob = json.dumps(self.to_json(), sort_keys=True).encode()
-            self._fp = (guard, hashlib.sha256(blob).hexdigest()[:16])
+        """Content hash of the graph (cached — LRU lookups re-query it):
+        SHA-256 of `canonical_json`, 16 hex digits."""
+        if not self.fingerprint_cached():
+            blob = self.canonical_json().encode()
+            self._fp = (self._fp_guard(),
+                        hashlib.sha256(blob).hexdigest()[:16])
         return self._fp[1]
+
+
+# Canonical text of `OpGraph.canonical_json`.  The fingerprint's value is
+# pinned (stored profiles, golden files, independent references), so the
+# text is exactly what `json.dumps(..., sort_keys=True)` gives: ", " and
+# ": " separators, ASCII escapes, keys in sorted order.  Ids and shapes
+# are ints (`add_tensor`/`add_op` make them so).
+_JSON = json.JSONEncoder(sort_keys=True)
+_json_str = json.encoder.encode_basestring_ascii
+
+# Text of each distinct params tuple and (shape, dtype); each memo is
+# cleared when it reaches MEMO_LIMIT entries, so a long search cannot
+# grow it without limit.
+MEMO_LIMIT = 65536
+_PARAMS_TEXT: Dict[Tuple[Tuple[str, Any], ...], str] = {}
+_TENSOR_TEXT: Dict[Tuple[Tuple[int, ...], str], str] = {}
+_MEMO_LOCK = threading.Lock()
+_MEMO_COUNTS = {"lookups": 0, "misses": 0}
+
+
+def _remember(memo: Dict[Any, str], key: Any, text: str) -> str:
+    if len(memo) >= MEMO_LIMIT:
+        memo.clear()
+    memo[key] = text
+    return text
+
+
+def _plain_params_text(params: Tuple[Tuple[str, Any], ...]) -> str:
+    """Text of params whose values are all int, str or None; memoized
+    when every key is a str too."""
+    items = []
+    for k, v in params:
+        if type(k) is not str:
+            return _JSON.encode([list(kv) for kv in params])
+        text = _json_str(v) if type(v) is str else (
+            "null" if v is None else str(v))
+        items.append(f"[{_json_str(k)}, {text}]")
+    return _remember(_PARAMS_TEXT, params, f"[{', '.join(items)}]")
+
+
+def _tensor_text(shape: Tuple[int, ...], dtype: str) -> str:
+    text = _JSON.encode({"dtype": dtype, "shape": list(shape)})
+    return _remember(_TENSOR_TEXT, (shape, dtype), text) \
+        if type(dtype) is str else text
+
+
+def fingerprint_memo_info() -> Dict[str, int]:
+    """Counters of `OpGraph.canonical_json`'s memo since start (or
+    `clear_fingerprint_memo`): params tuples and tensors answered
+    (``hits``) and encoded (``misses``), and the entries held now
+    (``size``, at most twice `MEMO_LIMIT`)."""
+    with _MEMO_LOCK:
+        lookups, misses = _MEMO_COUNTS["lookups"], _MEMO_COUNTS["misses"]
+    return {"hits": lookups - misses, "misses": misses,
+            "size": len(_PARAMS_TEXT) + len(_TENSOR_TEXT)}
+
+
+def clear_fingerprint_memo() -> None:
+    with _MEMO_LOCK:
+        _PARAMS_TEXT.clear()
+        _TENSOR_TEXT.clear()
+        _MEMO_COUNTS.update(lookups=0, misses=0)
 
 
 def op_signature(graph: OpGraph, node: OpNode) -> str:

@@ -46,7 +46,7 @@ from repro.core.composition import PredictorBank
 from repro.core.features import graph_features, graph_features_cached
 from repro.core.predictors.flat import resolve_backend
 from repro.core.fusion import fuse_graph
-from repro.core.ir import OpGraph
+from repro.core.ir import OpGraph, fingerprint_memo_info
 from repro.core.profiler import DeviceSetting, ProfileSession
 from repro.obs import Observability
 from repro.pipeline.hub import PredictorHub
@@ -261,6 +261,9 @@ class LatencyService:
         # Its span closes before `service.predict_batch` opens, so the
         # latter keeps the extent it always had.
         with tracer.span("service.fingerprint") as fp_span:
+            if tracer.enabled:
+                fp_span.set_attr("computed", sum(
+                    not g.fingerprint_cached() for g in graphs))
             fps = [g.fingerprint() for g in graphs]
             fp_span.set_attr("graphs", len(graphs))
         span = tracer.start_span(
@@ -571,7 +574,9 @@ class LatencyService:
                 "device_fused_runs": self.device_fused_runs,
                 "hub_epoch": self.hub.epoch,
             }
-        # Outside the counter lock: walks hub banks (its own structures).
+        # Outside the counter lock: the process-wide fingerprint memo has
+        # its own; the hub banks are their own structures.
+        out["fingerprint_memo"] = fingerprint_memo_info()
         out["device_residency"] = self.device_residency()
         return out
 

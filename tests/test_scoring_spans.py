@@ -80,7 +80,7 @@ def test_fused_predict_batch_span_tree(hub):
     spans = obs.tracer.export()
     (fp,) = _by_name(spans, "service.fingerprint")
     (pb,) = _by_name(spans, "service.predict_batch")
-    assert fp["attrs"] == {"graphs": 6}
+    assert fp["attrs"] == {"graphs": 6, "computed": 6}
     assert fp["end"] <= pb["start"]            # a sibling that ends first
     assert fp["parent"] == pb["parent"] is None
     children = [s for s in spans if s["parent"] == pb["sid"]]
@@ -114,10 +114,13 @@ def test_fused_predict_batch_span_tree(hub):
         assert tree[2]["attrs"] == {}
     # The report cache cleared, the same graphs featurize from the
     # feature cache: nothing is computed again.
+    # The same graph objects answer their fingerprints from their memo.
     svc.clear_cache()
     svc.predict_batch(graphs)
     feats = _by_name(obs.tracer.export(), "service.featurize")
     assert feats[-1]["attrs"] == {"graphs": 6, "computed": 0}
+    fps = _by_name(obs.tracer.export(), "service.fingerprint")
+    assert fps[-1]["attrs"] == {"graphs": 6, "computed": 0}
 
 
 def test_cached_batch_has_no_children(hub):
@@ -131,6 +134,27 @@ def test_cached_batch_has_no_children(hub):
     assert [s["name"] for s in new] == ["service.fingerprint",
                                         "service.predict_batch"]
     assert new[1]["attrs"]["fresh"] == 0
+
+
+def test_stats_report_the_fingerprint_memo(hub):
+    """New graph objects equal to scored ones are encoded from the memo:
+    every params tuple and tensor a hit, none a miss."""
+    obs = Observability()
+    svc = _service(hub, obs)
+    svc.predict_batch(_graphs(range(1225, 1229)))
+    before = svc.stats()["fingerprint_memo"]
+    assert set(before) == {"hits", "misses", "size"}
+    again = _graphs(range(1225, 1229))
+    svc.predict_batch(again)
+    after = svc.stats()["fingerprint_memo"]
+    assert _by_name(obs.tracer.export(), "service.fingerprint")[-1][
+        "attrs"] == {"graphs": 4, "computed": 4}
+    direct = sum(any(type(v) is tuple for _, v in n.params)
+                 for g in again for n in g.nodes)
+    assert after["misses"] - before["misses"] == direct
+    assert after["hits"] - before["hits"] == sum(
+        len(g.nodes) + len(g.tensors) for g in again) - direct
+    assert after["size"] == before["size"] > 0
 
 
 def test_numpy_tier_adds_no_tree_spans(hub):
